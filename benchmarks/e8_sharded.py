@@ -6,21 +6,16 @@ throughput at each width, plus a token-identity check: every mesh size
 must decode exactly the tokens the single-device engine decodes (the
 sharded-serving contract — see ``tests/test_mesh_serving.py``).
 
-Mesh sizes > 1 need > 1 device, and the host-device-count flag must be
-set *before* jax initializes — but the benchmark harness imports jax
-long before this section runs.  So ``run()`` re-executes this module as
-a **subprocess worker** with ``XLA_FLAGS=--xla_force_host_platform_
-device_count=8`` and relays the worker's rows.  On CPU the simulated
-devices share one socket, so the curve measures sharding *overhead*
-(collective cost per token), not speedup — the number that transfers to
-real accelerators is tokens/s staying flat-ish while per-device memory
-drops by N.
+The meshes are built in this process over ``jax.devices()``, and mesh
+sizes beyond the device count are skipped: a chip belongs to the one
+process that touched JAX first, so a child process could not reach it.
+On CPU, give the caller simulated devices with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``; they share one
+socket, so that curve measures sharding *overhead* (collective cost per
+token), not speedup.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 from typing import List
 
@@ -92,8 +87,7 @@ def _identity_tokens(eng):
     return out
 
 
-def worker() -> None:
-    """Runs under the forced 8-device host platform; prints e8_ rows."""
+def run() -> List[str]:
     import jax
     from repro.launch.mesh import make_serving_mesh
     from repro.models import build_model
@@ -102,6 +96,7 @@ def worker() -> None:
     params = model.init(jax.random.PRNGKey(0))
     n_dev = jax.device_count()
     ref_tokens, ref_tok_s = None, None
+    rows = []
     for n in MESH_SIZES:
         if n > n_dev:
             continue
@@ -113,29 +108,14 @@ def worker() -> None:
         else:
             assert tokens == ref_tokens, \
                 f"mesh={n} decoded different tokens than single-device"
-        print(f"e8_mesh{n},{1e6 / tok_s:.1f},"
-              f"tok_s={tok_s:.0f};devices={n};paged_burst_k8"
-              f";vs_mesh1=x{tok_s / ref_tok_s:.2f};token_identical=True",
-              flush=True)
-    print(f"e8_summary,{n_dev:.1f},simulated_devices={n_dev}"
-          f";mesh_sizes_token_identical=True;batch={BATCH}", flush=True)
-
-
-def run() -> List[str]:
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH="src")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.e8_sharded"], env=env, cwd=root,
-        capture_output=True, text=True, timeout=1200)
-    rows = [l for l in out.stdout.splitlines() if l.startswith("e8_")]
-    if out.returncode != 0 or not rows:
-        raise RuntimeError(
-            f"e8 worker failed (rc={out.returncode}):\n"
-            f"{out.stdout[-2000:]}\n{out.stderr[-2000:]}")
+        rows.append(f"e8_mesh{n},{1e6 / tok_s:.1f},"
+                    f"tok_s={tok_s:.0f};devices={n};paged_burst_k8"
+                    f";vs_mesh1=x{tok_s / ref_tok_s:.2f};token_identical=True")
+    rows.append(f"e8_summary,{n_dev:.1f},devices={n_dev}"
+                f";platform={jax.devices()[0].platform}"
+                f";mesh_sizes_token_identical=True;batch={BATCH}")
     return rows
 
 
 if __name__ == "__main__":
-    worker()
+    print("\n".join(run()))

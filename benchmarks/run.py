@@ -31,6 +31,9 @@ def main() -> None:
     json_path = args.json if args.json is not None \
         else ("" if only else "BENCH_serving.json")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (e1_multimodel, e2_ars, e3_mtcnn, e4_overhead, e5_batching,
                    e6_decode_loop, e7_frontdoor, e8_sharded, e9_speculative,
                    e10_quant, e11_chaos, roofline)

@@ -1,4 +1,4 @@
-"""smollm-360m — llama-arch small dense [hf:HuggingFaceTB/SmolLM-135M]."""
+"""smollm-360m — llama-arch small dense [hf:HuggingFaceTB/SmolLM-360M]."""
 from ..models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -7,5 +7,5 @@ CONFIG = ModelConfig(
     d_ff=2560, vocab_size=49152,
     norm="rmsnorm", mlp_act="swiglu", rope="rope",
     param_dtype="bfloat16", compute_dtype="bfloat16",
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
 )

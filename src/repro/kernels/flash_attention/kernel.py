@@ -66,7 +66,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B,H,S,hd); k,v: (B,KV,T,hd).  S % block_q == T % block_k == 0."""
     B, H, S, hd = q.shape
     KV, T = k.shape[1], k.shape[2]

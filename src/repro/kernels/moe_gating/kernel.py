@@ -32,7 +32,7 @@ def _gating_kernel(s_ref, vals_ref, idx_ref, *, k):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_t", "interpret"))
-def gating_topk(scores, k: int, *, block_t: int = 512, interpret: bool = True):
+def gating_topk(scores, k: int, *, block_t: int = 512, interpret: bool):
     """scores: (T, E), T multiple of block_t -> (vals (T,k), idx (T,k))."""
     T, E = scores.shape
     bt = min(block_t, T)

@@ -6,7 +6,7 @@ from typing import Optional
 import jax.numpy as jnp
 
 from .. import default_interpret
-from .kernel import selective_scan_kernel
+from .kernel import LANES, ROWS, selective_scan_kernel
 
 
 def selective_scan(dt, Bc, Cc, xs, A, D, h0=None, *, block_d: int = 128,
@@ -16,7 +16,11 @@ def selective_scan(dt, Bc, Cc, xs, A, D, h0=None, *, block_d: int = 128,
     assert h0 is None, "kernel path supports cold start only"
     B, S, di = xs.shape
     bd = min(block_d, di)
-    ct = min(chunk_t, S)
+    # whole aligned row groups, and whole lane tiles past one; padded
+    # steps have dt = 0, which leaves the state untouched
+    ct = -(-min(chunk_t, S) // ROWS) * ROWS
+    if ct > LANES:
+        ct = -(-ct // LANES) * LANES
     pad_d = (-di) % bd
     pad_t = (-S) % ct
     if pad_d:

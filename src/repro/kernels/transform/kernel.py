@@ -18,11 +18,20 @@ LANE = 128
 SUBLANE = 8
 
 
+def _widened(dtype):
+    """Mosaic casts unsigned integers to and from float only by way of
+    int32 (uint8 -> float32 is refused)."""
+    if jnp.issubdtype(dtype, jnp.unsignedinteger):
+        return jnp.int32
+    return dtype
+
+
 def _transform_kernel(x_ref, o_ref, *, scale, bias, lo, hi):
-    x = x_ref[...].astype(jnp.float32)
+    x = x_ref[...]
+    x = x.astype(_widened(x.dtype)).astype(jnp.float32)
     y = x * scale + bias
     y = jnp.clip(y, lo, hi)
-    o_ref[...] = y.astype(o_ref.dtype)
+    o_ref[...] = y.astype(_widened(o_ref.dtype)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "bias", "lo", "hi",
@@ -30,7 +39,7 @@ def _transform_kernel(x_ref, o_ref, *, scale, bias, lo, hi):
                                              "interpret"))
 def fused_transform_2d(x, *, scale: float, bias: float, lo: float, hi: float,
                        out_dtype=None, block_rows: int = 256,
-                       interpret: bool = True):
+                       interpret: bool):
     """x: (R, C) with C a multiple of 128; R a multiple of 8."""
     R, C = x.shape
     out_dtype = out_dtype or x.dtype

@@ -41,9 +41,14 @@ def fused_transform(x, *, scale: float = 1.0, bias: float = 0.0,
     # pad rows to a multiple of the grid block (grid must tile exactly)
     quantum = block_rows if rows > block_rows else SUBLANE
     rows_pad = -(-rows // quantum) * quantum
-    flat = jnp.ravel(x)
-    flat = jnp.pad(flat, (0, rows_pad * cols - n))
-    y = fused_transform_2d(flat.reshape(rows_pad, cols), scale=scale,
+    if n % cols:
+        x2 = jnp.pad(jnp.ravel(x), (0, rows * cols - n)).reshape(rows, cols)
+    else:
+        # pad whole rows: the TPU compiler takes minutes over a 1-D pad
+        # of a frame-sized uint8 vector
+        x2 = x.reshape(rows, cols)
+    x2 = jnp.pad(x2, ((0, rows_pad - rows), (0, 0)))
+    y = fused_transform_2d(x2, scale=scale,
                            bias=bias, lo=float(lo), hi=float(hi),
                            out_dtype=out_dtype, block_rows=block_rows,
                            interpret=default_interpret(interpret))

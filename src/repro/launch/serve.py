@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import threading
 import time
+from typing import List, Optional
 
 import jax
 import numpy as np
@@ -23,6 +24,7 @@ from ..configs import ARCH_IDS, get_config
 from ..models import build_model
 from ..models.config import ModelConfig, SSMConfig
 from ..serving import ServeEngine
+from .compile_cache import enable_compile_cache
 
 # demo-scale config per serving family (mirrors the conformance matrix
 # in tests/conftest.py): --family serves any of them through the same
@@ -211,18 +213,35 @@ def validate_args(args) -> None:
                 "pools have no sharding specs yet")
 
 
-def main():
-    args = build_parser().parse_args()
-    validate_args(args)
-
+def model_config(args) -> ModelConfig:
+    """The served config: a --family demo model or the --arch config
+    (reduced and float32 under --smoke)."""
     if args.family != "arch":
         cfg = FAMILY_CONFIGS[args.family]
     else:
         cfg = get_config(args.arch, smoke=args.smoke)
     if args.smoke:
         cfg = cfg.replace(param_dtype="float32", compute_dtype="float32")
+    return cfg
+
+
+def init_params(model, key, mesh=None):
+    """Random weights from ``key``, made by one jitted program.  Under a
+    mesh they are created in their serving shardings, one shard per
+    device, so a model larger than one chip never lands whole on the
+    first device; the values do not depend on the mesh."""
+    shardings = None
+    if mesh is not None:
+        from ..models.sharding import param_shardings
+        shardings = param_shardings(mesh, jax.eval_shape(model.init, key))
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
+def build_engine(args, cfg, mesh=None) -> ServeEngine:
+    """The paged ``ServeEngine`` the flags describe, with random weights
+    (seed 0; the draft model's seed 1)."""
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_params(model, jax.random.PRNGKey(0), mesh)
     draft_model = draft_params = None
     if args.spec_k > 0:
         name = args.draft_config or "tiny"
@@ -246,126 +265,65 @@ def main():
         print(f"speculative decoding: K={args.spec_k}, draft "
               f"{dcfg.arch_id} ({dcfg.n_layers}L d{dcfg.d_model})")
     tri = {"auto": None, "on": True, "off": False}
-    mesh = None
-    if args.mesh is not None:
-        from .mesh import make_serving_mesh
-        mesh = make_serving_mesh(model=args.mesh)
-        print(f"serving over mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}"
-              f" ({jax.device_count()} device(s) visible)")
-    engine = ServeEngine(model, params, batch_size=args.batch,
-                         capacity=args.prompt_len + args.max_new + 8,
-                         max_new_tokens=args.max_new,
-                         paged=tri[args.paged],
-                         block_size=args.block_size,
-                         num_blocks=args.num_blocks,
-                         prefill_chunk=args.prefill_chunk,
-                         share_prefix=tri[args.share_prefix],
-                         num_state_slots=args.num_state_slots,
-                         burst=args.burst,
-                         temperature=args.temperature,
-                         top_k=args.top_k, seed=args.seed,
-                         mesh=mesh, retain_cap=args.retain_cap,
-                         retain_ttl_s=args.retain_ttl_s,
-                         draft_model=draft_model, draft_params=draft_params,
-                         spec_k=args.spec_k, kv_dtype=args.kv_dtype)
+    return ServeEngine(model, params, batch_size=args.batch,
+                       capacity=args.prompt_len + args.max_new + 8,
+                       max_new_tokens=args.max_new,
+                       paged=tri[args.paged],
+                       block_size=args.block_size,
+                       num_blocks=args.num_blocks,
+                       prefill_chunk=args.prefill_chunk,
+                       share_prefix=tri[args.share_prefix],
+                       num_state_slots=args.num_state_slots,
+                       burst=args.burst,
+                       temperature=args.temperature,
+                       top_k=args.top_k, seed=args.seed,
+                       mesh=mesh, retain_cap=args.retain_cap,
+                       retain_ttl_s=args.retain_ttl_s,
+                       draft_model=draft_model, draft_params=draft_params,
+                       spec_k=args.spec_k, kv_dtype=args.kv_dtype)
 
+
+def make_requests(vocab: int, n: int, max_len: int, *, min_len: int = 4,
+                  shared: int = 0) -> List[np.ndarray]:
+    """``n`` random prompts (seed 0) with lengths in [max(min_len,
+    shared + 1), max_len), all starting with the same ``shared`` tokens."""
     rng = np.random.default_rng(0)
-    shared = rng.integers(0, cfg.vocab_size, args.shared_prompt).astype(np.int32)
-    lengths = [int(rng.integers(max(4, args.shared_prompt + 1),
-                                args.prompt_len))
-               for _ in range(args.requests)]
-    requests = [np.concatenate(
-                    [shared, rng.integers(0, cfg.vocab_size,
-                                          n - len(shared)).astype(np.int32)])
-                for n in lengths]
+    head = rng.integers(0, vocab, shared).astype(np.int32)
+    lengths = [int(rng.integers(max(min_len, shared + 1), max_len))
+               for _ in range(n)]
+    return [np.concatenate([head, rng.integers(0, vocab, k - shared)
+                            .astype(np.int32)]) for k in lengths]
 
-    if args.listen is not None:
-        from ..serving import TensorQueryClient, TensorQueryServer
-        lanes = [l.strip() for l in args.lanes.split(",") if l.strip()]
-        server = TensorQueryServer(engine, port=args.listen,
-                                   max_wait_ms=args.max_wait_ms_net,
-                                   pad_to=args.prompt_len).start()
-        print(f"tensor_query server listening on 127.0.0.1:{server.port} "
-              f"(lanes: {', '.join(lanes)})")
+
+def serve_over_tcp(engine, requests, *, port: int, lanes=("interactive",),
+                   pad_to: Optional[int] = None, max_wait_ms: float = 5.0):
+    """Serve ``requests`` through the front door: a ``TensorQueryServer``
+    on ``port`` (0 = ephemeral) (serversrc -> batcher -> engine filter
+    -> unbatcher -> serversink) and a loopback ``TensorQueryClient``.
+    Returns (results in request order, wall seconds from first submit
+    to last result)."""
+    from ..serving import TensorQueryClient, TensorQueryServer
+    server = TensorQueryServer(engine, port=port, max_wait_ms=max_wait_ms,
+                               pad_to=pad_to).start()
+    try:
+        t0 = time.perf_counter()
+        client = TensorQueryClient("127.0.0.1", server.port)
         try:
-            if not args.smoke:
-                # standing server: SIGTERM/SIGINT triggers a graceful
-                # drain — stop admitting, finish (or cancel) in-flight
-                # work so every client holds a terminal frame, exit 0
-                import signal
-                stop_evt = threading.Event()
-
-                def _on_signal(signum, frame):
-                    del frame
-                    print(f"signal {signum}: draining "
-                          f"(timeout {args.drain_timeout_s:.0f}s)",
-                          flush=True)
-                    stop_evt.set()
-                signal.signal(signal.SIGTERM, _on_signal)
-                signal.signal(signal.SIGINT, _on_signal)
-                while not stop_evt.wait(timeout=0.2):
-                    pass
-                clean = server.drain(timeout=args.drain_timeout_s)
-                print("drain complete" if clean
-                      else "drain timed out: remaining requests cancelled",
-                      flush=True)
-                return
-            t0 = time.perf_counter()
-            client = TensorQueryClient("127.0.0.1", server.port)
             qids = [client.submit(r, lane=lanes[i % len(lanes)])
                     for i, r in enumerate(requests)]
             rs = [client.result(q, timeout=300) for q in qids]
-            wall = time.perf_counter() - t0
-            total = sum(len(r.tokens) for r in rs if r.tokens is not None)
-            print(f"served {len(rs)} requests / {total} tokens over TCP "
-                  f"in {wall:.2f}s ({total / wall:.1f} tok/s)")
-            for r in rs[:3]:
-                print(f"  qid {r.qid}: status={r.status} "
-                      f"ttft={r.ttft_s:.3f}s tokens={list(r.tokens[:8])}...")
-            print(f"scheduler: prefills={engine.n_prefills} "
-                  f"joins={engine.n_joins} evictions={engine.n_evictions} "
-                  f"preemptions={engine.n_preemptions} "
-                  f"restores={engine.n_restores} expired={engine.n_expired}")
-            _print_spec_stats(engine)
-            client.close()
-        except KeyboardInterrupt:
-            pass
         finally:
-            server.stop()
-        return
+            client.close()
+        return rs, time.perf_counter() - t0
+    finally:
+        server.stop()
 
-    t0 = time.perf_counter()
-    if args.direct:
-        results = engine.serve(requests)
-        total_tokens = sum(len(r.tokens) for r in results)
-        n_results = len(results)
-    else:
-        from ..core import parse_pipeline
-        pipe = parse_pipeline(
-            "appsrc name=req ! tensor_batcher max_batch=%d max_wait_ms=%s ! "
-            "queue max_size=8 ! tensor_filter framework=python model=llm "
-            "max_batch=%d ! tensor_unbatcher ! tensor_sink name=out keep=true"
-            % (args.batch, args.max_wait_ms, args.batch),
-            models={"llm": engine.as_pipeline_filter()})
-        pipe.start()
-        # batcher stacks frames, so pad prompts to a common length up front
-        # (left-pad: the engine already treats leading zeros as padding)
-        maxlen = max(lengths)
-        for i, r in enumerate(requests):
-            pipe["req"].push(np.pad(r, (maxlen - len(r), 0)),
-                             meta={"request": i, "prompt_len": len(r)})
-        pipe["req"].end_of_stream()
-        pipe["out"].eos_seen.wait(timeout=300)
-        pipe.stop()
-        results = pipe["out"].buffers
-        total_tokens = sum(np.asarray(b.data).size for b in results)
-        n_results = len(results)
-    wall = time.perf_counter() - t0
 
-    print(f"served {n_results} requests / {total_tokens} tokens "
-          f"in {wall:.2f}s ({total_tokens / wall:.1f} tok/s)")
-    print(f"scheduler: prefills={engine.n_prefills} joins={engine.n_joins} "
-          f"evictions={engine.n_evictions}"
+def print_scheduler_stats(engine) -> None:
+    print(f"scheduler: prefills={engine.n_prefills} "
+          f"joins={engine.n_joins} evictions={engine.n_evictions} "
+          f"preemptions={engine.n_preemptions} "
+          f"restores={engine.n_restores} expired={engine.n_expired}"
           + (f" prefill_chunks={engine.n_prefill_chunks}" if engine.paged
              else ""))
     ls = engine.loop_stats()
@@ -377,6 +335,117 @@ def main():
           f"{ls['n_state_uploads']} state uploads, "
           f"{ls['n_burst_early_exits']} early exits")
     _print_spec_stats(engine)
+
+
+def _standing_server(engine, args, lanes) -> None:
+    """Serve until SIGTERM/SIGINT, then drain gracefully: stop
+    admitting, finish (or cancel) in-flight work so every client holds
+    a terminal frame."""
+    import signal
+    from ..serving import TensorQueryServer
+    server = TensorQueryServer(engine, port=args.listen,
+                               max_wait_ms=args.max_wait_ms_net,
+                               pad_to=args.prompt_len).start()
+    print(f"tensor_query server listening on 127.0.0.1:{server.port} "
+          f"(lanes: {', '.join(lanes)})")
+    stop_evt = threading.Event()
+
+    def _on_signal(signum, frame):
+        del frame
+        print(f"signal {signum}: draining "
+              f"(timeout {args.drain_timeout_s:.0f}s)", flush=True)
+        stop_evt.set()
+    signal.signal(signal.SIGTERM, _on_signal)
+    signal.signal(signal.SIGINT, _on_signal)
+    try:
+        while not stop_evt.wait(timeout=0.2):
+            pass
+        clean = server.drain(timeout=args.drain_timeout_s)
+        print("drain complete" if clean
+              else "drain timed out: remaining requests cancelled",
+              flush=True)
+    finally:
+        server.stop()
+
+
+def main(argv=None) -> int:
+    """Returns the exit code: 1 if any request ended in a status other
+    than ``ok``."""
+    args = build_parser().parse_args(argv)
+    validate_args(args)
+    enable_compile_cache()
+
+    cfg = model_config(args)
+    mesh = None
+    if args.mesh is not None:
+        from .mesh import make_serving_mesh
+        mesh = make_serving_mesh(model=args.mesh)
+        print(f"serving over mesh {dict(mesh.shape)}"
+              f" ({jax.device_count()} device(s) visible)")
+    engine = build_engine(args, cfg, mesh)
+    requests = make_requests(cfg.vocab_size, args.requests, args.prompt_len,
+                             shared=args.shared_prompt)
+    lanes = [l.strip() for l in args.lanes.split(",") if l.strip()]
+
+    if args.listen is not None and not args.smoke:
+        _standing_server(engine, args, lanes)
+        return 0
+
+    t0 = time.perf_counter()
+    if args.listen is not None:
+        rs, wall = serve_over_tcp(engine, requests, port=args.listen,
+                                  lanes=lanes, pad_to=args.prompt_len,
+                                  max_wait_ms=args.max_wait_ms_net)
+        statuses = [r.status for r in rs]
+        total_tokens = sum(len(r.tokens) for r in rs if r.tokens is not None)
+        print(f"served {len(rs)} requests / {total_tokens} tokens over TCP "
+              f"in {wall:.2f}s ({total_tokens / wall:.1f} tok/s)")
+        for r in rs[:3]:
+            print(f"  qid {r.qid}: status={r.status} "
+                  f"ttft={r.ttft_s:.3f}s tokens={list(r.tokens[:8])}...")
+    elif args.direct:
+        results = engine.serve(requests)
+        wall = time.perf_counter() - t0
+        statuses = [r.status for r in results]
+        total_tokens = sum(len(r.tokens) for r in results)
+        print(f"served {len(results)} requests / {total_tokens} tokens "
+              f"in {wall:.2f}s ({total_tokens / wall:.1f} tok/s)")
+        for r in results[:3]:
+            print(f"  req {r.request_id}: prompt[{len(r.prompt)}] -> "
+                  f"{r.tokens[:8]}... latency={r.latency_s:.3f}s")
+    else:
+        from ..core import parse_pipeline
+        pipe = parse_pipeline(
+            "appsrc name=req ! tensor_batcher max_batch=%d max_wait_ms=%s ! "
+            "queue max_size=8 ! tensor_filter framework=python model=llm "
+            "max_batch=%d pass_meta=true ! tensor_unbatcher ! "
+            "tensor_sink name=out keep=true"
+            % (args.batch, args.max_wait_ms, args.batch),
+            models={"llm": engine.as_pipeline_filter(use_meta=True)})
+        pipe.start()
+        # batcher stacks frames, so left-pad prompts to a common length;
+        # the engine filter strips the padding by meta["query"]
+        maxlen = max(len(r) for r in requests)
+        for i, r in enumerate(requests):
+            pipe["req"].push(np.pad(r, (maxlen - len(r), 0)),
+                             meta={"request": i,
+                                   "query": {"prompt_len": len(r)}})
+        pipe["req"].end_of_stream()
+        pipe["out"].eos_seen.wait(timeout=300)
+        pipe.stop()
+        results = pipe["out"].buffers
+        wall = time.perf_counter() - t0
+        statuses = [b.meta.get("status") for b in results]
+        statuses += ["lost"] * (len(requests) - len(results))
+        total_tokens = sum(np.asarray(b.data).size for b in results)
+        print(f"served {len(results)} requests / {total_tokens} tokens "
+              f"in {wall:.2f}s ({total_tokens / wall:.1f} tok/s)")
+        for b in results[:3]:
+            print(f"  req {b.meta.get('request')}: "
+                  f"prompt_len={b.meta['query']['prompt_len']} -> "
+                  f"{np.asarray(b.data)[:8]}...")
+
+    print_scheduler_stats(engine)
     if engine.paged:
         a = engine.allocator
         s = engine.pool_stats()
@@ -393,16 +462,13 @@ def main():
             print(f"prefix sharing: {engine.n_prefix_hits} hits, "
                   f"{engine.n_shared_tokens} prompt tokens served from "
                   f"resident blocks, {engine.n_cow_forks} COW forks")
-    if args.direct:
-        for r in results[:3]:
-            print(f"  req {r.request_id}: prompt[{len(r.prompt)}] -> "
-                  f"{r.tokens[:8]}... latency={r.latency_s:.3f}s")
-    else:
-        for b in results[:3]:
-            print(f"  req {b.meta.get('request')}: "
-                  f"prompt_len={b.meta.get('prompt_len')} -> "
-                  f"{np.asarray(b.data)[:8]}...")
+    failed = [st for st in statuses if st != "ok"]
+    if failed:
+        print(f"FAILED: {len(failed)} of {len(statuses)} requests did not "
+              f"end ok (statuses: {sorted(set(map(str, failed)))})")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -19,6 +19,7 @@ from ..data import TokenStream
 from ..models import build_model
 from ..models.frontends import fake_audio_frames, fake_vision_patches
 from ..training import Trainer
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.smoke:

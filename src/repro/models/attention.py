@@ -359,6 +359,9 @@ def gqa_paged_step(p, cfg: ModelConfig, x, k_store, v_store, page_table,
     v_store = constrain(v_store, None, None, None, "model")
     out = paged_attention(q, paged_gather(k_store, page_table),
                           paged_gather(v_store, page_table), positions)
+    # attention runs in the pool's precision; the residual stream stays
+    # in the compute dtype (a bf16 model may keep an f32 pool)
+    out = out.astype(x.dtype)
     return out.reshape(B, T, -1) @ p["wo"], k_store, v_store
 
 
@@ -427,7 +430,7 @@ def gqa_paged_step_quant(p, cfg: ModelConfig, x, k_store, v_store,
                            paged_gather(k_scale, page_table))
     v_gath = dequantize_kv(paged_gather(v_store, page_table),
                            paged_gather(v_scale, page_table))
-    out = paged_attention(q, k_gath, v_gath, positions)
+    out = paged_attention(q, k_gath, v_gath, positions).astype(x.dtype)
     return (out.reshape(B, T, -1) @ p["wo"],
             k_store, v_store, k_scale, v_scale)
 
@@ -511,7 +514,8 @@ def gqa_decode(p, cfg: ModelConfig, x, k_cache, v_cache, pos):
     idx = jnp.mod(pos, cap) if window else pos
     k_cache = _dynamic_token_update(k_cache, k, idx)
     v_cache = _dynamic_token_update(v_cache, v, idx)
-    out = decode_attention(q, k_cache, v_cache, pos, window=window)
+    out = decode_attention(q, k_cache, v_cache, pos,
+                           window=window).astype(x.dtype)
     B = x.shape[0]
     return out.reshape(B, 1, -1) @ p["wo"], k_cache, v_cache
 

@@ -14,7 +14,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # (regex on path, spec WITHOUT the stacked-leading-None)
 # dp = FSDP axis name tuple; tp = "model"
@@ -139,6 +139,15 @@ def param_specs(params, dp=("data",), axis_sizes=None):
     return jax.tree_util.tree_map_with_path(spec_of, params)
 
 
+def param_shardings(mesh, params):
+    """``NamedSharding`` pytree placing ``params`` (arrays or
+    ``jax.ShapeDtypeStruct`` leaves) on ``mesh`` by ``param_specs``:
+    "model" is the tensor axis, every other axis is FSDP."""
+    dp = tuple(a for a in mesh.axis_names if a != "model") or ("data",)
+    specs = param_specs(params, dp=dp, axis_sizes=dict(mesh.shape))
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
+
+
 def cache_specs(cache, dp=("data",), shard_seq_when_batch1: bool = True,
                 axis_sizes=None):
     """KV/state caches: batch over dp; heads over model; for batch-1
@@ -239,39 +248,36 @@ def paged_cache_specs(cache, axis_sizes=None):
 
 
 def _context_mesh():
-    """The mesh installed by ``with mesh:`` (None outside a context)."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and m.axis_names:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    return None
+    """The mesh installed by ``with mesh:`` or ``jax.set_mesh`` (None
+    when no mesh is active).  jax 0.9.0's ``get_abstract_mesh()`` does
+    not see the ``with mesh:`` context that the serving engine and the
+    dry runs enter, so that one is read first."""
+    from jax._src import mesh as mesh_lib
+    m = mesh_lib.thread_resources.env.physical_mesh
+    if not m.empty:
+        return m
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x, *spec_parts):
-    """with_sharding_constraint if a concrete mesh context is active."""
-    try:
-        mesh = _context_mesh()
-        if mesh is None:
-            return x
-        names = set(mesh.axis_names)
-        flat = []
-        for p in spec_parts:
-            if p is None:
-                flat.append(None)
-            elif isinstance(p, tuple):
-                kept = tuple(q for q in p if q in names)
-                flat.append(kept if kept else None)
-            else:
-                flat.append(p if p in names else None)
-        return jax.lax.with_sharding_constraint(x, P(*flat))
-    except Exception:  # noqa: BLE001 — no mesh context: no-op
+    """``with_sharding_constraint`` when a mesh is active, else ``x``.
+
+    Axis names the mesh lacks are dropped, and so are axes that do not
+    divide their dim evenly (the rule ``param_specs`` follows); any
+    other failure is a real error and propagates."""
+    mesh = _context_mesh()
+    if mesh is None:
         return x
+    names = set(mesh.axis_names)
+    flat = []
+    for p in spec_parts:
+        if p is None:
+            flat.append(None)
+        elif isinstance(p, tuple):
+            kept = tuple(q for q in p if q in names)
+            flat.append(kept if kept else None)
+        else:
+            flat.append(p if p in names else None)
+    flat = _filter_divisible(flat, x.shape, dict(mesh.shape))
+    return jax.lax.with_sharding_constraint(x, P(*flat))

@@ -258,14 +258,11 @@ class ServeEngine:
                     "shards the paged block pool (the dense per-slot cache "
                     "has no sharded layout)")
             from jax.sharding import NamedSharding, PartitionSpec
-            from ..models.sharding import param_specs
-            axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            dp = tuple(a for a in mesh.axis_names if a != "model") \
-                or ("data",)
-            pspecs = param_specs(params, dp=dp, axis_sizes=axis_sizes)
-            self.params = jax.device_put(
-                params,
-                jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs))
+            from ..models.sharding import param_shardings
+            # a no-op for params created in place (see
+            # launch.serve.init_params); others are resharded here
+            self.params = jax.device_put(params,
+                                         param_shardings(mesh, params))
             self._replicated = NamedSharding(mesh, PartitionSpec())
         self._prefill = jax.jit(make_prefill_step(model, capacity, cache_dtype),
                                 static_argnames=())
@@ -741,10 +738,7 @@ class ServeEngine:
                          ("prefill", self._prefill)):
             if fn is None:
                 continue
-            try:
-                out[name] = fn._cache_size()
-            except AttributeError:      # older jax: no cache introspection
-                pass
+            out[name] = fn._cache_size()
         return out
 
     def _sharding_ctx(self):
@@ -1020,15 +1014,20 @@ class ServeEngine:
         are written back into the meta for the downstream sink.
         ``on_submit(rid, meta)`` fires immediately after each row is
         submitted — before any token is generated — so a streaming
-        front door can route ``stream_cb`` tokens by request id."""
+        front door can route ``stream_cb`` tokens by request id.  A row
+        whose meta is None is a batch-bucket pad row (``TensorFilter``
+        pads a batch up to its bucket) and is not served."""
         pad = self.eos_id if self.eos_id is not None else 0
 
         def fn(prompts, metas=None):
             prompts = np.asarray(prompts, np.int32)
-            ms = list(metas) if (use_meta and metas is not None) \
-                else [None] * len(prompts)
+            with_meta = use_meta and metas is not None
+            ms = list(metas) if with_meta else [None] * len(prompts)
             rids: List[Optional[int]] = []
             for row, m in zip(prompts, ms):
+                if with_meta and m is None:
+                    rids.append(None)
+                    continue
                 q = m.get("query", {}) if isinstance(m, dict) else {}
                 plen = int(q.get("prompt_len", 0)) or row.shape[0]
                 # per-row isolation: a poison prompt (bad shape, vocab

@@ -137,22 +137,35 @@ def test_interpret_defaults_to_backend_autodetect():
     """Every kernels/*/ops.py entry point defaults interpret=None and
     resolves it through default_interpret(): CPU hosts autodetect to
     interpret mode (compiled Pallas silently miscompiles or crashes on
-    CPU), explicit overrides pass through untouched."""
+    CPU), explicit overrides pass through untouched.  The kernel.py
+    entry points below them take ``interpret`` as a required keyword,
+    so a direct call can never run interpreted on a chip unasked."""
     import inspect
 
     from repro.kernels import default_interpret
+    from repro.kernels.decode_attention import kernel as dk
     from repro.kernels.decode_attention.ops import (
         decode_attention_bhd, paged_decode_attention_bhd,
         paged_decode_attention_quant_bhd)
+    from repro.kernels.flash_attention.kernel import flash_attention
     from repro.kernels.flash_attention.ops import flash_attention_bshd
+    from repro.kernels.moe_gating.kernel import gating_topk
     from repro.kernels.moe_gating.ops import topk
+    from repro.kernels.ssm_scan.kernel import selective_scan_kernel
     from repro.kernels.ssm_scan.ops import selective_scan
+    from repro.kernels.transform.kernel import fused_transform_2d
     from repro.kernels.transform.ops import fused_transform
     for fn in (decode_attention_bhd, paged_decode_attention_bhd,
                paged_decode_attention_quant_bhd, flash_attention_bshd,
                topk, selective_scan, fused_transform):
         sig = inspect.signature(fn)
         assert sig.parameters["interpret"].default is None, fn.__name__
+    for fn in (dk.decode_attention, dk.paged_decode_attention,
+               dk.paged_decode_attention_quant, flash_attention,
+               gating_topk, selective_scan_kernel, fused_transform_2d):
+        param = inspect.signature(fn).parameters["interpret"]
+        assert param.kind is inspect.Parameter.KEYWORD_ONLY, fn.__name__
+        assert param.default is inspect.Parameter.empty, fn.__name__
     assert default_interpret() == (jax.default_backend() == "cpu")
     assert default_interpret(True) is True
     assert default_interpret(False) is False
@@ -162,6 +175,7 @@ def test_interpret_defaults_to_backend_autodetect():
 
 @pytest.mark.parametrize("B,S,di,N,bd,ct", [
     (1, 16, 32, 4, 16, 8), (2, 48, 96, 8, 32, 16), (1, 100, 64, 16, 64, 32),
+    (1, 300, 32, 4, 32, 256),   # two 128-lane column slices per chunk
 ])
 def test_ssm_scan_kernel(B, S, di, N, bd, ct):
     from repro.kernels.ssm_scan import ops

@@ -88,3 +88,28 @@ def test_kv_dtype_choices_validate_standalone(kv_dtype):
 def test_kv_dtype_rejects_unknown_choice():
     with pytest.raises(SystemExit):
         _args("--kv-dtype", "fp4")
+
+
+# -- exit status --------------------------------------------------------------
+
+@pytest.mark.parametrize("status,code", [("ok", 0), ("error", 1)])
+def test_main_exit_code_follows_request_statuses(monkeypatch, status, code):
+    """A smoke run whose requests do not all end ok must exit non-zero:
+    a chip failure the engine absorbs into failed requests cannot pass
+    as a served run."""
+    import numpy as np
+
+    from repro.launch import serve
+    from repro.serving import ServeEngine
+    from repro.serving.engine import GenerationResult
+
+    def fake_serve(self, prompts):
+        return [GenerationResult(request_id=i, prompt=p,
+                                 tokens=np.zeros(2, np.int32), latency_s=0.0,
+                                 status=status)
+                for i, p in enumerate(prompts)]
+    monkeypatch.setattr(ServeEngine, "serve", fake_serve)
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    assert serve.main(["--family", "transformer", "--smoke", "--direct",
+                       "--requests", "2", "--batch", "2",
+                       "--max-new", "2"]) == code

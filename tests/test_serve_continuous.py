@@ -143,3 +143,21 @@ def test_pipeline_filter_adapter_row_order():
     assert out.shape == (4, 3)
     for i in range(4):
         assert list(out[i]) == _expected(prompts[i], 3)
+
+
+def test_pipeline_filter_skips_bucket_pad_rows():
+    """A TensorFilter pads a 3-frame batch up to its bucket of 4 with a
+    meta-less zero row; the meta-aware engine filter must serve only
+    the 3 real requests."""
+    from repro.core.elements.filter import TensorFilter
+    eng = _engine(batch_size=4, max_new_tokens=3)
+    filt = TensorFilter("llm", framework="python", max_batch=4,
+                        pass_meta=True, fn=eng.as_pipeline_filter(use_meta=True))
+    prompts = np.stack([np.asarray([i + 1, i + 2], np.int32) for i in range(3)])
+    metas = [{"request": i} for i in range(3)]
+    (out,) = filt.invoke_batched([prompts], 3, metas=metas)
+    assert eng.n_requests == 3
+    assert out.shape == (3, 3)
+    for i in range(3):
+        assert list(out[i]) == _expected(prompts[i], 3)
+        assert metas[i]["status"] == "ok"

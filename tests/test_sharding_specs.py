@@ -158,7 +158,8 @@ from repro.training import TrainState, make_train_step
 from repro.optim import adamw_init
 
 dp, tp = {mesh}
-mesh = jax.make_mesh((dp, tp), ("data", "model"))
+mesh = jax.make_mesh((dp, tp), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("{arch}", smoke=True)
 model = build_model(cfg, remat=True)
 params_s = jax.eval_shape(model.init, jax.random.PRNGKey(0))
