@@ -21,7 +21,9 @@ deadline honoured), ``TOKENS`` server->client (incremental new-token
 delta), ``DONE`` server->client (full token tensor + terminal status),
 ``ERROR`` (malformed/oversized request, or a request-level failure; an
 ERROR with qid 0xFFFFFFFF is connection-scoped — protocol desync, the
-peer closes after sending it), ``CANCEL`` client->server (abandon a
+peer closes after sending it), ``TIMING`` server->client (the
+request's server-side durations, sent immediately before its DONE or
+ERROR; see ``TIMING_FIELDS``), ``CANCEL`` client->server (abandon a
 request: the server evicts it and answers ``DONE(status=cancelled)``
 with whatever tokens it generated), ``CREDIT`` client->server (u32
 payload: grant N more TOKENS frames for this qid — credit-based flow
@@ -32,10 +34,12 @@ chosen by the client and is scoped to its connection, so the server
 routes responses by (connection, qid) while the engine schedules by its
 own request id.
 
-Version 2 added CANCEL/CREDIT and the credit semantics.  A frame whose
-version does not match is answered with a connection-scoped ERROR and
-the connection is closed — after a header disagreement the stream can
-never be resynchronized, so failing loudly beats silently desyncing.
+Version 2 added CANCEL/CREDIT and the credit semantics.  TIMING came
+later without a version bump: a v2 peer skips frame types it does not
+know.  A frame whose version does not match is answered with a
+connection-scoped ERROR and the connection is closed — after a header
+disagreement the stream can never be resynchronized, so failing loudly
+beats silently desyncing.
 
 ``TensorQueryServerSrc`` pushes one buffer per request: a ``(pad_to,)``
 int32 row, left-padded with zeros (the engine treats leading zeros as
@@ -54,6 +58,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..element import Element, Pad
 from ..stream import Buffer
@@ -64,7 +69,7 @@ VERSION = 2                         # v2: CANCEL/CREDIT + credit flow control
 HDR = struct.Struct("!2sBBIBBdI")   # magic, ver, type, qid, lane, status,
                                     # deadline, payload_len
 MSG_REQUEST, MSG_TOKENS, MSG_DONE, MSG_ERROR = 1, 2, 3, 4
-MSG_CANCEL, MSG_CREDIT = 5, 6
+MSG_CANCEL, MSG_CREDIT, MSG_TIMING = 5, 6, 7
 CONN_QID = 0xFFFFFFFF               # qid of connection-scoped ERROR frames
 # absurd-length guard: a corrupted/hostile header must fail the parse,
 # not commit the reader to a multi-GB recv
@@ -77,6 +82,19 @@ STATUS_CODES = {"ok": 0, "timeout": 1, "expired": 2, "cancelled": 3,
 STATUS_NAMES = {v: k for k, v in STATUS_CODES.items()}
 _DTYPE_CODES = {"int32": 1, "float32": 2, "int64": 3, "uint8": 4}
 _DTYPE_NAMES = {v: k for k, v in _DTYPE_CODES.items()}
+# TIMING payload: a float32 tensor of these durations in seconds, in
+# this order, each between two stamps taken on the server's monotonic
+# clock:
+#   ingress  arrival at the server source -> engine submit (micro-batch
+#            wait and the worker queue)
+#   queue    engine submit -> first admission to a slot (scheduler)
+#   prefill  admission -> first token sampled (the prompt's mixed steps)
+#   decode   first token -> engine finish
+#   hold     engine finish -> terminal frame handed to the connection
+TIMING_FIELDS = ("ingress", "queue", "prefill", "decode", "hold")
+# the meta keys each stamp is read from, in time order: meta["query"]
+# holds the arrival, the engine filter writes the four engine stamps
+_STAMPS = ("t_submit", "t_admit", "t_first", "t_finish")
 
 
 class ProtocolError(ValueError):
@@ -126,6 +144,19 @@ def unpack_credit(payload: bytes) -> int:
     if len(payload) != 4:
         raise ValueError(f"CREDIT payload must be 4 bytes, got {len(payload)}")
     return struct.unpack("!I", payload)[0]
+
+
+def timing_record(meta: Dict[str, Any], t_handoff: float
+                  ) -> Optional[np.ndarray]:
+    """The TIMING durations (``TIMING_FIELDS`` order, float32 seconds)
+    of a request whose meta carries every stamp, else None: a request
+    failed before admission or before its first token has no record."""
+    q = meta.get("query") or {}
+    stamps = [q.get("t_arrival")] + [meta.get(k) for k in _STAMPS]
+    if any(t is None for t in stamps):
+        return None
+    stamps.append(t_handoff)
+    return np.diff(np.asarray(stamps, np.float64)).astype(np.float32)
 
 
 def recv_exact(sock: socket.socket, n: int) -> Optional[bytes]:
@@ -221,13 +252,14 @@ class QueryConnection:
         """Enqueue one frame for the writer thread; never blocks.
         Returns False if the connection is dead or a best-effort TOKENS
         frame was dropped on queue overflow.  Terminal DONE/ERROR
-        frames flush the qid's paused TOKENS ahead of themselves and
-        retire its credit state — the route is over either way."""
+        frames, and the TIMING frame sent just before one, flush the
+        qid's paused TOKENS ahead of themselves and retire its credit
+        state — the route is over either way."""
         if not self.alive:
             return False
         frame = pack_frame(msg_type, qid, payload, status=status)
         with self._q_lock:
-            if msg_type in (MSG_DONE, MSG_ERROR):
+            if msg_type in (MSG_DONE, MSG_ERROR, MSG_TIMING):
                 for paused in self._paused.pop(qid, ()):
                     self._q.append(paused)
                 self._credit.pop(qid, None)
@@ -597,8 +629,11 @@ class TensorQueryServerSink(Element):
     Expects per-request buffers (downstream of ``tensor_unbatcher``)
     whose meta carries the ``query`` routing dict from
     ``TensorQueryServerSrc`` plus the ``status`` / ``n_tokens`` fields
-    the engine filter wrote back.  Buffers without routing metadata are
-    counted and dropped (e.g. locally injected test traffic).
+    and the engine stamps (``t_submit`` / ``t_admit`` / ``t_first`` /
+    ``t_finish``) the engine filter wrote back.  A request with every
+    stamp gets a TIMING frame just before its terminal frame.  Buffers
+    without routing metadata are counted and dropped (e.g. locally
+    injected test traffic).
 
     ``on_done(meta)`` — if given — fires after the terminal frame is
     handed to the connection, whether or not the send succeeded; the
@@ -624,8 +659,21 @@ class TensorQueryServerSink(Element):
         if conn is None:
             self.n_unroutable += 1
             return
+        qid = int(q["qid"])
+        with TraceAnnotation("frontdoor.done", qid=qid):
+            self._send_terminal(conn, qid, buf)
+        if self.on_done is not None:
+            self.on_done(buf.meta)    # terminal: the route is dead either way
+
+    def _send_terminal(self, conn: QueryConnection, qid: int,
+                       buf: Buffer) -> None:
+        """The request's TIMING record, where it has one, then its DONE
+        or ERROR frame."""
         status_name = buf.meta.get("status", "ok")
         status = STATUS_CODES.get(status_name, STATUS_CODES["error"])
+        timing = timing_record(buf.meta, time.monotonic())
+        if timing is not None:
+            conn.send_frame(MSG_TIMING, qid, pack_tensor(timing))
         # count before the send: a client that acts on the DONE frame
         # (and e.g. reads this counter) must never observe it lagging
         self.n_sent += 1
@@ -634,15 +682,13 @@ class TensorQueryServerSink(Element):
             # the failure message instead of a token tensor
             self.n_errors += 1
             msg = str(buf.meta.get("error", "request failed")).encode()
-            ok = conn.send_frame(MSG_ERROR, int(q["qid"]), msg, status=status)
+            ok = conn.send_frame(MSG_ERROR, qid, msg, status=status)
         else:
             tokens = np.asarray(buf.chunks[0], np.int32).reshape(-1)
             n = buf.meta.get("n_tokens")
             if n is not None:
                 tokens = tokens[:int(n)]
-            ok = conn.send_frame(MSG_DONE, int(q["qid"]), pack_tensor(tokens),
+            ok = conn.send_frame(MSG_DONE, qid, pack_tensor(tokens),
                                  status=status)
         if not ok:
             self.n_sent -= 1          # connection died under the send
-        if self.on_done is not None:
-            self.on_done(buf.meta)    # terminal: the route is dead either way

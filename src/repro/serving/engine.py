@@ -103,6 +103,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .kv_cache import (ROOT_DIGEST, BlockAllocator, CacheFullError,
                        DeviceSlotState, StateStore, chain_digest)
@@ -125,18 +126,27 @@ class GenerationResult:
     status: str = "ok"
     ttft_s: Optional[float] = None    # submit -> first generated token
     error: Optional[str] = None       # failure message (status "error")
+    # time.monotonic() stamps of the request's way through the engine:
+    # submit, first admission to a slot, first token sampled, finish.
+    # A stamp the request never reached is None.
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_finish: Optional[float] = None
 
 
 class _Slot:
     __slots__ = ("rid", "prompt", "tokens", "t_submit", "done", "lane",
-                 "deadline", "tag", "status", "t_first", "adm_seq")
+                 "deadline", "tag", "status", "t_admit", "t_first",
+                 "adm_seq")
 
     def __init__(self, req: SchedRequest, first_token: int,
-                 eos_id: Optional[int], max_new: int):
+                 eos_id: Optional[int], max_new: int, t_admit: float):
         self.rid = req.rid
         self.prompt = req.prompt
         self.tokens: List[int] = [int(first_token)]
         self.t_submit = req.t_submit
+        self.t_admit = t_admit
         self.done = (eos_id is not None and int(first_token) == eos_id) \
             or max_new <= 1
         self.lane = req.lane
@@ -152,8 +162,8 @@ class _PagedSlot:
     in the engine's ``_lengths`` array; this tracks ownership."""
     __slots__ = ("rid", "prompt", "tokens", "t_submit", "done", "blocks",
                  "reserve_left", "prefill_off", "digests", "lane",
-                 "deadline", "tag", "status", "t_first", "adm_seq",
-                 "spec_rounds", "spec_deficit", "spec_prev")
+                 "deadline", "tag", "status", "t_admit", "t_first",
+                 "adm_seq", "spec_rounds", "spec_deficit", "spec_prev")
 
     def __init__(self, req: SchedRequest, blocks: List[int],
                  reserve_left: int, prefill_off: int = 0,
@@ -171,7 +181,11 @@ class _PagedSlot:
         self.deadline = req.deadline
         self.tag = req.tag
         self.status = "ok"
-        self.t_first: Optional[float] = None
+        # a restore after preemption keeps the first admission and the
+        # first token
+        self.t_admit = time.monotonic() if req.t_admit is None \
+            else req.t_admit
+        self.t_first: Optional[float] = req.t_first
         self.adm_seq = 0
         # host mirrors of the speculative slot-state keys (spec engines
         # only): rounds run (PRNG stream position), draft-cache deficit
@@ -515,7 +529,6 @@ class ServeEngine:
             put=(lambda v: jax.device_put(np.asarray(v), self._replicated))
             if mesh is not None else None)
         # scheduler counters
-        self.n_batches = 0            # prefill launches (back-compat alias)
         self.n_requests = 0
         self.n_prefills = 0
         self.n_joins = 0              # requests admitted mid-decode
@@ -550,6 +563,7 @@ class ServeEngine:
         self.n_restarts = 0           # engine pool rebuilds performed
         self.n_cancelled = 0          # requests cancelled via cancel()
         self._consec_failures = 0     # resets on every clean step
+        self._tick = 0                # step() calls: the trace's step number
 
     # -- synchronous fixed batch API (kept for benchmarks/back-compat) ------
     def generate_batch(self, prompts: np.ndarray,
@@ -560,11 +574,10 @@ class ServeEngine:
         sampling path)."""
         B, S = prompts.shape
         assert B == self.batch_size, (B, self.batch_size)
-        t0 = time.perf_counter()
         with self._sharding_ctx():
-            return self._generate_batch_impl(prompts, extra_embeds, t0)
+            return self._generate_batch_impl(prompts, extra_embeds)
 
-    def _generate_batch_impl(self, prompts, extra_embeds, t0):
+    def _generate_batch_impl(self, prompts, extra_embeds):
         B, S = prompts.shape
         logits, cache = self._prefill(self.params, jnp.asarray(prompts),
                                       extra_embeds)
@@ -576,9 +589,7 @@ class ServeEngine:
                                            jnp.int32(pos))
             out.append(np.asarray(token))
             pos += 1
-        self.n_batches += 1
         self.n_requests += B
-        self.last_batch_latency_s = time.perf_counter() - t0
         return np.concatenate(out, axis=1)
 
     # -- continuous batching ------------------------------------------------
@@ -640,7 +651,9 @@ class ServeEngine:
             tokens=np.asarray(slot.tokens, np.int32),
             latency_s=now - slot.t_submit, status=slot.status,
             ttft_s=None if slot.t_first is None
-            else slot.t_first - slot.t_submit)
+            else slot.t_first - slot.t_submit,
+            t_submit=slot.t_submit, t_admit=slot.t_admit,
+            t_first=slot.t_first, t_finish=now)
 
     def pool_stats(self) -> Optional[Dict[str, int]]:
         """Block-pool occupancy incl. shared vs private split (paged),
@@ -762,13 +775,20 @@ class ServeEngine:
         Restarts are bounded by ``max_restarts`` *consecutive*
         failures; past that every in-flight and queued request is
         failed and the exception propagates.
+
+        Each tick is an ``engine_step`` span in a profiler trace, with
+        its phases (``engine.admit``, ``engine.evict``,
+        ``engine.prepare``, ``engine.dispatch``, ``engine.drain``,
+        ``engine.emit``) as spans inside it on the host's plane.
         """
         fault = self.fault_plan.fire("engine_step") if self.fault_plan \
             else None
+        self._tick += 1
         try:
             if fault is not None and fault.action == "raise":
                 raise fault.make_exc()
-            with self._sharding_ctx():
+            with self._sharding_ctx(), StepTraceAnnotation(
+                    "engine_step", step_num=self._tick):
                 out = self._step_impl()
         except Exception as exc:
             return self._handle_step_failure(exc)
@@ -891,8 +911,10 @@ class ServeEngine:
     def _step_impl(self) -> List[GenerationResult]:
         if self.paged:
             return self._step_paged()
-        self._admit()
-        finished = self._evict()
+        with TraceAnnotation("engine.admit"):
+            self._admit()
+        with TraceAnnotation("engine.evict"):
+            finished = self._evict()
         if self.n_active == 0:
             return finished
         if self._pos >= self.capacity:
@@ -901,21 +923,25 @@ class ServeEngine:
                 if slot is not None:
                     slot.done = True
             return finished + self._evict()
-        with self._lock:
-            pending = self.scheduler.pending
-        # queue non-empty -> single-step so the next eviction admits at
-        # once; otherwise burst, capped at the cache strip's remainder
-        k = 1 if pending else min(self.burst, self.max_burst)
-        k = max(1, min(k, self.capacity - self._pos))
-        st = self._dev.device(self._dense_state)
-        out = self._burst_fn(self.params, self._cache, st,
-                             jnp.int32(self._pos), np.int32(k))
+        with TraceAnnotation("engine.prepare"):
+            with self._lock:
+                pending = self.scheduler.pending
+            # queue non-empty -> single-step so the next eviction admits
+            # at once; otherwise burst, capped at the cache strip's
+            # remainder
+            k = 1 if pending else min(self.burst, self.max_burst)
+            k = max(1, min(k, self.capacity - self._pos))
+            st = self._dev.device(self._dense_state)
+        with TraceAnnotation("engine.dispatch", k=k):
+            out = self._burst_fn(self.params, self._cache, st,
+                                 jnp.int32(self._pos), np.int32(k))
         self._cache = out[0]
         self._dev.adopt(out[1])
         self._drain_burst(out[2], out[3],
                           out[4] if self.trace_logits else None,
                           k=k, paged=False)
-        return finished + self._evict()
+        with TraceAnnotation("engine.evict"):
+            return finished + self._evict()
 
     def serve(self, requests: List[np.ndarray], timeout_s: float = 120.0,
               lane: str = "interactive") -> List[GenerationResult]:
@@ -1010,8 +1036,10 @@ class ServeEngine:
         dicts a ``pass_meta`` TensorFilter forwards: each row's
         ``meta["query"]`` may carry ``prompt_len`` (strip transport
         left-padding), ``lane``, ``deadline`` (relative seconds) and
-        ``tag``; after serving, ``status`` / ``ttft_s`` / ``n_tokens``
-        are written back into the meta for the downstream sink.
+        ``tag``; after serving, ``status`` / ``n_tokens`` and the
+        engine stamps ``t_submit`` / ``t_admit`` / ``t_first`` /
+        ``t_finish`` are written back into the meta for the downstream
+        sink.
         ``on_submit(rid, meta)`` fires immediately after each row is
         submitted — before any token is generated — so a streaming
         front door can route ``stream_cb`` tokens by request id.  A row
@@ -1083,8 +1111,10 @@ class ServeEngine:
                     continue
                 out[i, : len(r.tokens)] = r.tokens
                 if isinstance(ms[i], dict):
-                    ms[i].update(status=r.status, ttft_s=r.ttft_s,
-                                 n_tokens=int(len(r.tokens)))
+                    ms[i].update(status=r.status,
+                                 n_tokens=int(len(r.tokens)),
+                                 t_submit=r.t_submit, t_admit=r.t_admit,
+                                 t_first=r.t_first, t_finish=r.t_finish)
                     if r.status == "error":
                         ms[i]["error"] = r.error or err or "request failed"
             return out
@@ -1169,10 +1199,16 @@ class ServeEngine:
         with the device's ``active`` flags."""
         bufs = (tok_buf, val_buf) if logit_buf is None \
             else (tok_buf, val_buf, logit_buf)
-        got = jax.device_get(bufs)
+        with TraceAnnotation("engine.drain", k=k):
+            got = jax.device_get(bufs)
+        with TraceAnnotation("engine.emit", k=k):
+            self._emit_burst(got, k=k, paged=paged)
+
+    def _emit_burst(self, got, *, k: int, paged: bool) -> None:
+        """Fold a drained burst's tokens into the slots and stream them."""
         self.n_host_syncs += 1
         toks, valid = got[0], got[1]
-        logits = got[2] if logit_buf is not None else None
+        logits = got[2] if len(got) > 2 else None
         n_steps = int(valid.any(axis=1).sum())
         self.n_bursts += 1
         self.n_device_steps += n_steps
@@ -1218,10 +1254,16 @@ class ServeEngine:
         event, and accumulates the acceptance statistics."""
         bufs = (tok_buf, val_buf) if logit_buf is None \
             else (tok_buf, val_buf, logit_buf)
-        got = jax.device_get(bufs)
+        with TraceAnnotation("engine.drain", k=k):
+            got = jax.device_get(bufs)
+        with TraceAnnotation("engine.emit", k=k):
+            self._emit_spec_burst(got, k=k)
+
+    def _emit_spec_burst(self, got, *, k: int) -> None:
+        """Fold a drained speculative burst into the slots and stream it."""
         self.n_host_syncs += 1
         toks, valid = got[0], got[1]
-        logits = got[2] if logit_buf is not None else None
+        logits = got[2] if len(got) > 2 else None
         n_rounds = int(valid.any(axis=(1, 2)).sum())
         self.n_bursts += 1
         self.n_device_steps += n_rounds
@@ -1320,6 +1362,7 @@ class ServeEngine:
                 fresh = False
         if not joins:
             return
+        t_admit = time.monotonic()
         B = self.batch_size
         if fresh:
             maxlen = max(req.prompt.shape[0] for _, req in joins)
@@ -1337,7 +1380,6 @@ class ServeEngine:
                 rids[slot_i] = req.rid
             first_np = self._sample_rows(logits, rids, np.zeros((B,), np.int32))
         self.n_prefills += 1
-        self.n_batches += 1
         if fresh:
             self._cache = cache
         else:
@@ -1351,7 +1393,7 @@ class ServeEngine:
                 self.logit_trace.setdefault(req.rid, []).append(
                     logits_np[slot_i].copy())
             slot = _Slot(req, first_np[slot_i], self.eos_id,
-                         self.max_new_tokens)
+                         self.max_new_tokens, t_admit)
             slot.t_first = now
             slot.adm_seq = self._adm_seq
             self._adm_seq += 1
@@ -1396,9 +1438,11 @@ class ServeEngine:
         # allocation traffic — an idle server still ticks through here,
         # so expired prefix blocks are retired even with no admissions
         # or completions in flight (no-op without retain_ttl_s)
-        self.allocator.sweep()
-        self._admit_paged()
-        finished = self._evict_paged()
+        with TraceAnnotation("engine.admit"):
+            self.allocator.sweep()
+            self._admit_paged()
+        with TraceAnnotation("engine.evict"):
+            finished = self._evict_paged()
         busy = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         if not busy:
             return finished
@@ -1410,11 +1454,45 @@ class ServeEngine:
         if self.share_prefix:
             for i, slot in busy:
                 self._register_full_pages(i, slot)
-        return finished + self._evict_paged()
+        with TraceAnnotation("engine.evict"):
+            return finished + self._evict_paged()
 
     def _step_paged_mixed(self, busy) -> None:
         """One mixed prefill+decode megastep (T = ``prefill_chunk``)."""
         T = self.prefill_chunk
+        with TraceAnnotation("engine.prepare", t=T):
+            staged = self._stage_mixed(busy, T)
+        if staged is None:
+            return
+        tokens, t_valid, emit, st = staged
+        with TraceAnnotation("engine.dispatch", t=T):
+            if self._spec:
+                cache, dcache, st, sampled, logits = self._mixed_fn(
+                    self.params, self.draft_params, self._paged_cache,
+                    self._draft_cache, st, jnp.asarray(tokens),
+                    jnp.asarray(t_valid), jnp.asarray(emit))
+                self._draft_cache = dcache
+            else:
+                cache, st, sampled, logits = self._mixed_fn(
+                    self.params, self._paged_cache, st, jnp.asarray(tokens),
+                    jnp.asarray(t_valid), jnp.asarray(emit))
+        self._paged_cache = cache
+        self._dev.adopt(st)
+        self.n_prefill_chunks += 1
+        self.n_device_steps += 1
+        with TraceAnnotation("engine.drain", t=T):
+            if self.trace_logits:
+                sampled_np, logits_np = jax.device_get((sampled, logits))
+            else:
+                sampled_np, logits_np = np.asarray(sampled), None
+        self.n_host_syncs += 1
+        with TraceAnnotation("engine.emit", t=T):
+            self._emit_mixed(busy, tokens, t_valid, sampled_np, logits_np)
+
+    def _stage_mixed(self, busy, T: int):
+        """The mixed step's inputs, with COW forks and page extensions
+        done and the slot state on the device; None when no slot has
+        work."""
         tokens = np.zeros((self.batch_size, T), np.int32)
         t_valid = np.zeros((self.batch_size,), np.int32)
         emit = np.zeros((self.batch_size,), bool)
@@ -1434,33 +1512,19 @@ class ServeEngine:
                 t_valid[i] = 1
                 emit[i] = True
         if not t_valid.any():
-            return
+            return None
         for i, slot in busy:
             if t_valid[i]:
                 self._cow_write_range(i, slot, int(self._lengths[i]),
                                       int(t_valid[i]))
                 self._extend_blocks(i, slot,
                                     int(self._lengths[i]) + int(t_valid[i]))
-        st = self._dev.device(self._paged_state)
-        if self._spec:
-            cache, dcache, st, sampled, logits = self._mixed_fn(
-                self.params, self.draft_params, self._paged_cache,
-                self._draft_cache, st, jnp.asarray(tokens),
-                jnp.asarray(t_valid), jnp.asarray(emit))
-            self._draft_cache = dcache
-        else:
-            cache, st, sampled, logits = self._mixed_fn(
-                self.params, self._paged_cache, st, jnp.asarray(tokens),
-                jnp.asarray(t_valid), jnp.asarray(emit))
-        self._paged_cache = cache
-        self._dev.adopt(st)
-        self.n_prefill_chunks += 1
-        self.n_device_steps += 1
-        if self.trace_logits:
-            sampled_np, logits_np = jax.device_get((sampled, logits))
-        else:
-            sampled_np, logits_np = np.asarray(sampled), None
-        self.n_host_syncs += 1
+        return tokens, t_valid, emit, self._dev.device(self._paged_state)
+
+    def _emit_mixed(self, busy, tokens, t_valid, sampled_np,
+                    logits_np) -> None:
+        """Advance the slots by what the mixed step consumed, and hand
+        each slot that finished its prompt or decoded its token."""
         for i, slot in busy:
             if not t_valid[i]:
                 continue
@@ -1477,7 +1541,6 @@ class ServeEngine:
                 if slot.prefill_off < len(slot.prompt):
                     continue          # more chunks to go; no token yet
                 self.n_prefills += 1
-                self.n_batches += 1
             if self.trace_logits:
                 self.logit_trace.setdefault(slot.rid, []).append(
                     logits_np[i].copy())
@@ -1504,6 +1567,35 @@ class ServeEngine:
             pending = self.scheduler.pending
         k = 1 if pending else min(self.burst, self.max_burst)
         k = max(1, k)
+        with TraceAnnotation("engine.prepare", k=k):
+            st = self._stage_burst(busy, k)
+        if st is None:
+            return
+        with TraceAnnotation("engine.dispatch", k=k):
+            if self._spec:
+                out = self._burst_fn(self.params, self.draft_params,
+                                     self._paged_cache, self._draft_cache,
+                                     st, np.int32(k))
+            else:
+                out = self._burst_fn(self.params, self._paged_cache, st,
+                                     np.int32(k))
+        if self._spec:
+            self._paged_cache, self._draft_cache = out[0], out[1]
+            self._dev.adopt(out[2])
+            self._drain_spec_burst(out[3], out[4],
+                                   out[5] if self.trace_logits else None,
+                                   k=k)
+            return
+        self._paged_cache = out[0]
+        self._dev.adopt(out[1])
+        self._drain_burst(out[2], out[3],
+                          out[4] if self.trace_logits else None,
+                          k=k, paged=True)
+
+    def _stage_burst(self, busy, k: int):
+        """Extend and COW-fork every active slot's pages over the
+        burst's write range; the slot state on the device, or None when
+        no slot is active."""
         any_active = False
         for i, slot in busy:
             if slot.done:
@@ -1527,24 +1619,8 @@ class ServeEngine:
                 self._extend_blocks(i, slot, target)
             any_active = True
         if not any_active:
-            return
-        st = self._dev.device(self._paged_state)
-        if self._spec:
-            out = self._burst_fn(self.params, self.draft_params,
-                                 self._paged_cache, self._draft_cache, st,
-                                 np.int32(k))
-            self._paged_cache, self._draft_cache = out[0], out[1]
-            self._dev.adopt(out[2])
-            self._drain_spec_burst(out[3], out[4],
-                                   out[5] if self.trace_logits else None,
-                                   k=k)
-            return
-        out = self._burst_fn(self.params, self._paged_cache, st, np.int32(k))
-        self._paged_cache = out[0]
-        self._dev.adopt(out[1])
-        self._drain_burst(out[2], out[3],
-                          out[4] if self.trace_logits else None,
-                          k=k, paged=True)
+            return None
+        return self._dev.device(self._paged_state)
 
     def _match_prefix(self, prompt: np.ndarray) \
             -> Tuple[List[int], List[bytes], int]:
@@ -1888,7 +1964,8 @@ class ServeEngine:
         slot = self._slots[slot_i]
         req = SchedRequest(rid=slot.rid, prompt=slot.prompt, lane=slot.lane,
                            deadline=slot.deadline, tag=slot.tag,
-                           t_submit=slot.t_submit)
+                           t_submit=slot.t_submit, t_admit=slot.t_admit,
+                           t_first=slot.t_first)
         if slot.tokens and slot.prefill_off >= len(slot.prompt):
             if self._gather_pages is None:
                 raise RuntimeError(

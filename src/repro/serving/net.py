@@ -20,6 +20,9 @@ the DONE frame from the serversink carries the authoritative full
 sequence plus terminal status, so a TOKENS delta lost to the
 registration race (a token emitted between ``submit`` and the
 ``on_submit`` route registration) costs an increment, never data.
+Just before the DONE frame, a TIMING frame carries the server-side
+durations (ingress, queue, prefill, decode, hold), which the client
+keeps as ``QueryResult.server_timing``.
 
 Fault tolerance (protocol v2): the server resolves MSG_CANCEL frames to
 engine request ids (including cancels racing the batcher — they are
@@ -46,18 +49,21 @@ import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.elements.query import (CONN_QID, HDR, LANE_CODES, LANE_NAMES,
                                    MAGIC, MSG_CANCEL, MSG_CREDIT, MSG_DONE,
-                                   MSG_ERROR, MSG_REQUEST, MSG_TOKENS,
-                                   STATUS_CODES, STATUS_NAMES, VERSION,
-                                   ProtocolError, pack_credit, pack_frame,
-                                   pack_tensor, read_frame, unpack_tensor)
+                                   MSG_ERROR, MSG_REQUEST, MSG_TIMING,
+                                   MSG_TOKENS, STATUS_CODES, STATUS_NAMES,
+                                   TIMING_FIELDS, VERSION, ProtocolError,
+                                   pack_credit, pack_frame, pack_tensor,
+                                   read_frame, unpack_tensor)
 
 __all__ = ["TensorQueryClient", "TensorQueryServer",
            "HDR", "MAGIC", "VERSION", "CONN_QID",
            "MSG_REQUEST", "MSG_TOKENS", "MSG_DONE", "MSG_ERROR",
-           "MSG_CANCEL", "MSG_CREDIT", "LANE_CODES", "LANE_NAMES",
+           "MSG_CANCEL", "MSG_CREDIT", "MSG_TIMING", "TIMING_FIELDS",
+           "LANE_CODES", "LANE_NAMES",
            "STATUS_CODES", "STATUS_NAMES", "ProtocolError",
            "pack_frame", "pack_tensor", "pack_credit",
            "read_frame", "unpack_tensor"]
@@ -65,6 +71,12 @@ __all__ = ["TensorQueryClient", "TensorQueryServer",
 
 class QueryResult:
     """Client-side per-request state, filled in by the reader thread.
+
+    ``server_timing`` is the server's TIMING record, ``{name: seconds}``
+    over ``TIMING_FIELDS``, set before ``done``; None where the server
+    sent none (a request failed before admission or before its first
+    token).  Only durations cross the wire, so it needs no clock shared
+    with the server.
 
     The submission parameters (prompt/lane/deadline/credit) are kept so
     a reconnecting client can idempotently resubmit a query the server
@@ -85,6 +97,7 @@ class QueryResult:
         self.tokens: Optional[np.ndarray] = None  # authoritative, from DONE
         self.status: Optional[str] = None
         self.error: Optional[str] = None
+        self.server_timing: Optional[Dict[str, float]] = None
         self.done = threading.Event()
 
     @property
@@ -292,7 +305,11 @@ class TensorQueryClient:
                 if res is None or res.done.is_set():
                     continue            # unknown, or duplicate terminal
                 now = time.monotonic()
-                if msg_type == MSG_TOKENS:
+                if msg_type == MSG_TIMING:
+                    res.server_timing = dict(zip(
+                        TIMING_FIELDS,
+                        unpack_tensor(payload).astype(float).tolist()))
+                elif msg_type == MSG_TOKENS:
                     if res.t_first is None:
                         res.t_first = now
                     res.stream.extend(
@@ -548,6 +565,10 @@ class TensorQueryServer:
                             status=STATUS_CODES["cancelled"])
 
     def _on_tokens(self, rid: int, new_tokens) -> None:
+        with TraceAnnotation("frontdoor.stream", rid=rid):
+            self._stream(rid, new_tokens)
+
+    def _stream(self, rid: int, new_tokens) -> None:
         with self._routes_lock:
             route = self._routes.get(rid)
         if route is None:

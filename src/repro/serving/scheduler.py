@@ -61,6 +61,9 @@ class SchedRequest:
     deadline: Optional[float] = None
     tag: Any = None
     t_submit: float = 0.0
+    # first admission and first token, kept across a preemption
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
     # -- preemption restore state --
     tokens: List[int] = dataclasses.field(default_factory=list)
     length: int = 0                  # cache positions filled at spill time
