@@ -1,4 +1,4 @@
-"""Public op: decode attention in model-native layout with padding."""
+"""Public ops: decode and paged attention in model-native layout."""
 from __future__ import annotations
 
 from typing import Optional
@@ -6,7 +6,7 @@ from typing import Optional
 import jax.numpy as jnp
 
 from .. import default_interpret
-from .kernel import (decode_attention, paged_decode_attention,
+from .kernel import (decode_attention, paged_attention,
                      paged_decode_attention_quant)
 
 
@@ -27,19 +27,20 @@ def decode_attention_bhd(q, k_cache, v_cache, length, *, block_k: int = 512,
     return o[:, None]
 
 
-def paged_decode_attention_bhd(q, k_pages, v_pages, page_table, lengths, *,
-                               interpret: Optional[bool] = None):
-    """Paged decode attention in the serving engine's layout.
+def paged_attention_bthd(q, k_pages, v_pages, page_table, lengths, t_valid,
+                         *, layer=None, interpret: Optional[bool] = None):
+    """Paged attention in the serving engine's layout, read in place.
 
-    q: (B,1,H,hd); k_pages/v_pages: (num_blocks, block_size, KV, hd) —
-    the ``ServeEngine`` paged-cache leaf layout; page_table: (B,P);
-    lengths: (B,).  Returns (B,1,H,hd).
+    q: (B,T,H,hd); k_pages/v_pages: (num_blocks, KV, block_size, hd) —
+    the ``ServeEngine`` paged-cache leaf layout — or the (L, ...) stack
+    of every layer's pools with ``layer`` the one read; page_table:
+    (B,P); lengths: (B,) positions cached before this step; t_valid:
+    (B,) real tokens of this step.  Returns (B,T,H,hd).
     """
-    kt = jnp.moveaxis(k_pages, 2, 1)   # -> (nb, KV, bs, hd)
-    vt = jnp.moveaxis(v_pages, 2, 1)
-    o = paged_decode_attention(q[:, 0], kt, vt, page_table, lengths,
-                               interpret=default_interpret(interpret))
-    return o[:, None]
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    return paged_attention(q, k_pages, v_pages, page_table, lengths, t_valid,
+                           layer, interpret=default_interpret(interpret))
 
 
 def paged_decode_attention_quant_bhd(q, k_pages, v_pages, k_scale, v_scale,
@@ -47,16 +48,12 @@ def paged_decode_attention_quant_bhd(q, k_pages, v_pages, k_scale, v_scale,
                                      interpret: Optional[bool] = None):
     """Int8 paged decode attention in the serving engine's layout.
 
-    q: (B,1,H,hd) float; k_pages/v_pages: (num_blocks, block_size, KV,
+    q: (B,1,H,hd) float; k_pages/v_pages: (num_blocks, KV, block_size,
     hd) int8 — the ``kv_dtype="int8"`` paged-cache leaf layout;
-    k_scale/v_scale: (num_blocks, block_size, KV) float32 per-row
+    k_scale/v_scale: (num_blocks, KV, block_size) float32 per-row
     scales; page_table: (B,P); lengths: (B,).  Returns (B,1,H,hd).
     """
-    kt = jnp.moveaxis(k_pages, 2, 1)    # -> (nb, KV, bs, hd)
-    vt = jnp.moveaxis(v_pages, 2, 1)
-    kst = jnp.moveaxis(k_scale, 2, 1)   # -> (nb, KV, bs)
-    vst = jnp.moveaxis(v_scale, 2, 1)
-    o = paged_decode_attention_quant(q[:, 0], kt, vt, kst, vst,
-                                     page_table, lengths,
+    o = paged_decode_attention_quant(q[:, 0], k_pages, v_pages, k_scale,
+                                     v_scale, page_table, lengths,
                                      interpret=default_interpret(interpret))
     return o[:, None]

@@ -271,41 +271,111 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     return _grouped_out(probs, v_cache)
 
 
-def paged_gather(storage, page_table):
+LANES = 128
+
+
+def paged_page_shape(block_size: int, head_dim: int) -> Tuple[int, int]:
+    """(rows, lanes) of one KV head's page in the block pool.
+
+    A page holds the head's (block_size, head_dim) rows in row-major
+    order.  A head narrower than 128 is folded onto 128 lanes, ``128 //
+    head_dim`` positions to a row, when the page fills whole rows: the
+    TPU then lays the pool out row-major and unpadded, as the paged
+    attention kernel copies it, where a 64-wide minor dim would be
+    padded to 128 or moved off the lanes by the device's default
+    layout.  Otherwise the page is (block_size, head_dim) as it is."""
+    if (head_dim < LANES and LANES % head_dim == 0
+            and (block_size * head_dim) % LANES == 0):
+        return block_size * head_dim // LANES, LANES
+    return block_size, head_dim
+
+
+def paged_gather(storage, page_table, layer=None):
     """Materialize per-slot logical views of a shared block pool.
 
-    storage: (num_blocks, block_size, ...); page_table: (B, P) int32.
-    Returns (B, P * block_size, ...) — row ``b`` holds slot ``b``'s
-    logical positions 0..P*bs-1 in order.  Entries past the slot's true
-    length are whatever the pointed-to blocks hold; callers mask by
-    length.
+    storage: (num_blocks, KV, block_size, ...), or (L, num_blocks, KV,
+    block_size, ...) with ``layer`` picking one layer's pool (a K/V pool
+    of folded pages is passed unfolded, ``(..., block_size, hd)``);
+    page_table: (B, P) int32.  Returns (B, P * block_size, KV, ...) —
+    row ``b`` holds slot ``b``'s logical positions 0..P*bs-1 in order.
+    Entries past the slot's true length are whatever the pointed-to
+    blocks hold; callers mask by length.
     """
     B, P = page_table.shape
-    g = storage[page_table]                       # (B, P, bs, ...)
-    return g.reshape((B, P * storage.shape[1]) + storage.shape[2:])
+    g = storage[page_table] if layer is None \
+        else storage[layer, page_table]           # (B, P, KV, bs, ...)
+    g = jnp.swapaxes(g, 2, 3)                     # (B, P, bs, KV, ...)
+    return g.reshape((B, P * g.shape[2]) + g.shape[3:])
 
 
-def paged_scatter(storage, vals, page_table, lengths, t_valid):
-    """Write per-slot token runs into the shared block pool.
+def paged_scatter(storage, vals, page_table, lengths, t_valid, layer=None):
+    """Write per-slot token runs into the shared block pool, in place.
 
-    storage: (num_blocks, block_size, ...); vals: (B, T, ...).
-    Token ``t`` of row ``b`` lands at logical position ``lengths[b] + t``
-    iff ``t < t_valid[b]``; invalid tokens (padding, inactive slots,
+    storage: a K/V pool (num_blocks, KV, rows, lanes) of
+    ``paged_page_shape`` pages with vals (B, T, KV, hd), or a scale pool
+    (num_blocks, KV, block_size) with vals (B, T, KV); either with a
+    leading layer axis when ``layer`` names the layer written.  Token
+    ``t`` of row ``b`` lands at logical position ``lengths[b] + t`` iff
+    ``t < t_valid[b]``; invalid tokens (padding, inactive slots,
     positions past the page table) are dropped, not written.
+
+    The write is page by page: the few pages a run touches are read,
+    its positions merged in, and each page written back whole at a
+    one-dimensional index -- one scatter op on the device with a few
+    updates a slot, where a row or a position at a time would be
+    hundreds.  A page no run touches is not written, and a page one
+    run writes is that slot's own (shared pages are forked before the
+    step), so no two updates meet.
     """
-    nb, bs = storage.shape[:2]
+    lead = 0 if layer is None else 1
+    nb, KV = storage.shape[lead:lead + 2]
     B, T = vals.shape[:2]
     P = page_table.shape[1]
-    pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]   # (B,T)
-    page = pos // bs
+    page_shape = storage.shape[lead + 1:]                # (KV, rows[, lanes])
+    bs = int(np.prod(page_shape[1:])) // int(np.prod(vals.shape[3:]))
+    flat = storage.reshape((-1,) + page_shape)           # (L*nb, KV, ...)
+    # the pages a run of T positions can touch, and each one's positions
+    n_pg = (T + bs - 2) // bs + 1
+    page = lengths[:, None] // bs + jnp.arange(n_pg, dtype=jnp.int32)[None]
+    tok = (page[..., None] * bs + jnp.arange(bs, dtype=jnp.int32)
+           - lengths[:, None, None])                     # (B, n_pg, bs)
+    new = (tok >= 0) & (tok < t_valid[:, None, None])
     block = jnp.take_along_axis(page_table, jnp.clip(page, 0, P - 1), axis=1)
-    ok = (jnp.arange(T)[None, :] < t_valid[:, None]) & (page < P)
-    flat_idx = jnp.where(ok, block * bs + pos % bs, nb * bs)  # OOB -> drop
-    flat = storage.reshape((nb * bs,) + storage.shape[2:])
-    flat = flat.at[flat_idx.reshape(-1)].set(
-        vals.astype(storage.dtype).reshape((B * T,) + vals.shape[2:]),
-        mode="drop")
+    if layer is not None:
+        block = block + layer * nb
+    at = jnp.where(new.any(-1) & (page < P), block, flat.shape[0])
+    # the pages as (B, n_pg, KV, bs, ...) with the run's values merged in
+    old = flat[jnp.clip(at, 0, flat.shape[0] - 1)]
+    old = old.reshape((B, n_pg, KV, bs) + vals.shape[3:])
+    val = jnp.take_along_axis(
+        vals, jnp.clip(tok, 0, T - 1).reshape(
+            (B, n_pg * bs) + (1,) * (vals.ndim - 2)), axis=1)
+    val = jnp.swapaxes(val.reshape((B, n_pg, bs) + vals.shape[2:]), 2, 3)
+    keep = new.reshape((B, n_pg, 1, bs) + (1,) * (vals.ndim - 3))
+    pages = jnp.where(keep, val.astype(storage.dtype), old)
+    flat = flat.at[at.reshape(-1)].set(
+        pages.reshape((B * n_pg,) + page_shape), mode="drop")
     return flat.reshape(storage.shape)
+
+
+def _unfold(store, hd):
+    """A K/V pool of folded pages as (..., block_size, hd)."""
+    return store.reshape(store.shape[:-2] + (-1, hd))
+
+
+def paged_attention_path(pool_dtype) -> str:
+    """Which path serves ``gqa_paged_step`` here, decided from what the
+    trace can observe: ``"pallas"`` (``kernels.decode_attention``'s
+    ``paged_attention``, reading live pages in place) on a TPU backend
+    with no mesh active and a float pool; ``"jnp"`` (gather the whole
+    page table, then ``paged_attention``) otherwise — on CPU, under a
+    mesh, whose head_dim-sharded pool the kernel does not split, and
+    for the int8 pool."""
+    from .sharding import _context_mesh
+    if (jax.default_backend() == "tpu" and _context_mesh() is None
+            and jnp.issubdtype(pool_dtype, jnp.floating)):
+        return "pallas"
+    return "jnp"
 
 
 def paged_attention(q, k_gath, v_gath, positions, *,
@@ -331,34 +401,47 @@ def paged_attention(q, k_gath, v_gath, positions, *,
 
 
 def gqa_paged_step(p, cfg: ModelConfig, x, k_store, v_store, page_table,
-                   lengths, t_valid):
+                   lengths, t_valid, layer=None):
     """Process T tokens per slot through a block-paged KV cache.
 
-    x: (B,T,D); k_store/v_store: (num_blocks, block_size, KV, hd) shared
-    pools; page_table: (B,P) int32; lengths: (B,) tokens already cached
-    per slot; t_valid: (B,) how many of this call's T tokens are real
-    for each slot (0 = slot idle this step).
+    x: (B,T,D); k_store/v_store: (num_blocks, KV, rows, lanes) shared
+    pools of ``paged_page_shape`` pages, or the (L, ...) stack of every
+    layer's pools with ``layer`` the one this call reads and writes (so
+    the stack is updated in place, never sliced out and written back);
+    page_table: (B,P) int32; lengths: (B,) tokens already cached per
+    slot; t_valid: (B,) how many of this call's T tokens are real for
+    each slot (0 = slot idle this step).
 
     One function covers both serving phases: decode is T=1/t_valid=1,
     chunked prefill is T=chunk with t_valid up to chunk — slots may mix
     phases freely within a call.  K/V are scattered through the page
-    table *before* the gather, so in-chunk causal self-attention falls
-    out of the position mask.  Returns (out (B,T,D), k_store, v_store).
+    table *before* attention reads them, so in-chunk causal
+    self-attention falls out of the position mask.  The path is
+    ``paged_attention_path``'s.  Returns (out (B,T,D), k_store,
+    v_store).
     """
     from .sharding import constrain
     B, T, _ = x.shape
     positions = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     q, k, v = _project_qkv(p, cfg, x)
     q, k = _rope_qk(cfg, q, k, positions)
-    k_store = paged_scatter(k_store, k, page_table, lengths, t_valid)
-    v_store = paged_scatter(v_store, v, page_table, lengths, t_valid)
+    k_store = paged_scatter(k_store, k, page_table, lengths, t_valid, layer)
+    v_store = paged_scatter(v_store, v, page_table, lengths, t_valid, layer)
     # under a mesh: the pool stays block-replicated / head_dim-sharded
     # through the scatter, so XLA never resorts to resharding the whole
     # pool around the donated update (no-op without a mesh context)
-    k_store = constrain(k_store, None, None, None, "model")
-    v_store = constrain(v_store, None, None, None, "model")
-    out = paged_attention(q, paged_gather(k_store, page_table),
-                          paged_gather(v_store, page_table), positions)
+    lead = (None,) * (k_store.ndim - 1)
+    k_store = constrain(k_store, *lead, "model")
+    v_store = constrain(v_store, *lead, "model")
+    if paged_attention_path(k_store.dtype) == "pallas":
+        from ..kernels.decode_attention.ops import paged_attention_bthd
+        out = paged_attention_bthd(q, k_store, v_store, page_table, lengths,
+                                   t_valid, layer=layer)
+    else:
+        hd = q.shape[-1]
+        out = paged_attention(
+            q, paged_gather(_unfold(k_store, hd), page_table, layer),
+            paged_gather(_unfold(v_store, hd), page_table, layer), positions)
     # attention runs in the pool's precision; the residual stream stays
     # in the compute dtype (a bf16 model may keep an f32 pool)
     out = out.astype(x.dtype)
@@ -396,12 +479,14 @@ def dequantize_kv(q, scale):
 
 
 def gqa_paged_step_quant(p, cfg: ModelConfig, x, k_store, v_store,
-                         k_scale, v_scale, page_table, lengths, t_valid):
-    """Int8 variant of ``gqa_paged_step``.
+                         k_scale, v_scale, page_table, lengths, t_valid,
+                         layer=None):
+    """Int8 variant of ``gqa_paged_step`` (always the jnp path).
 
-    k_store/v_store: (num_blocks, block_size, KV, hd) int8 pools;
-    k_scale/v_scale: (num_blocks, block_size, KV) float32 per-row scale
-    pools that ride the same page-table indirection.  New K/V rows are
+    k_store/v_store: (num_blocks, KV, rows, lanes) int8 pools;
+    k_scale/v_scale: (num_blocks, KV, block_size) float32 per-row scale
+    pools that ride the same page-table indirection; each with a leading
+    layer axis when ``layer`` is given.  New K/V rows are
     quantized post-RoPE and scattered alongside their scales; the gather
     dequantizes back to f32 before the (unchanged) ``paged_attention``
     core, so the only numeric difference from the f32 path is the int8
@@ -415,21 +500,24 @@ def gqa_paged_step_quant(p, cfg: ModelConfig, x, k_store, v_store,
     q, k = _rope_qk(cfg, q, k, positions)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
-    k_store = paged_scatter(k_store, kq, page_table, lengths, t_valid)
-    v_store = paged_scatter(v_store, vq, page_table, lengths, t_valid)
-    # scale rows (B,T,KV) take the same flat-scatter path — paged_scatter
-    # is generic over trailing dims, so the (nb,bs,KV) scale pool is just
-    # a storage with one fewer trailing axis
-    k_scale = paged_scatter(k_scale, ks, page_table, lengths, t_valid)
-    v_scale = paged_scatter(v_scale, vs, page_table, lengths, t_valid)
-    k_store = constrain(k_store, None, None, None, "model")
-    v_store = constrain(v_store, None, None, None, "model")
-    k_scale = constrain(k_scale, None, None, None)
-    v_scale = constrain(v_scale, None, None, None)
-    k_gath = dequantize_kv(paged_gather(k_store, page_table),
-                           paged_gather(k_scale, page_table))
-    v_gath = dequantize_kv(paged_gather(v_store, page_table),
-                           paged_gather(v_scale, page_table))
+    k_store = paged_scatter(k_store, kq, page_table, lengths, t_valid, layer)
+    v_store = paged_scatter(v_store, vq, page_table, lengths, t_valid, layer)
+    # scale rows (B,T,KV) take the same page-wise scatter: the
+    # (nb,KV,bs) scale pool is a storage with no head_dim axis
+    k_scale = paged_scatter(k_scale, ks, page_table, lengths, t_valid, layer)
+    v_scale = paged_scatter(v_scale, vs, page_table, lengths, t_valid, layer)
+    lead = (None,) * (k_store.ndim - 1)
+    k_store = constrain(k_store, *lead, "model")
+    v_store = constrain(v_store, *lead, "model")
+    k_scale = constrain(k_scale, *lead)
+    v_scale = constrain(v_scale, *lead)
+    hd = q.shape[-1]
+    k_gath = dequantize_kv(
+        paged_gather(_unfold(k_store, hd), page_table, layer),
+        paged_gather(k_scale, page_table, layer))
+    v_gath = dequantize_kv(
+        paged_gather(_unfold(v_store, hd), page_table, layer),
+        paged_gather(v_scale, page_table, layer))
     out = paged_attention(q, k_gath, v_gath, positions).astype(x.dtype)
     return (out.reshape(B, T, -1) @ p["wo"],
             k_store, v_store, k_scale, v_scale)

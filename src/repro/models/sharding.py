@@ -205,7 +205,7 @@ def paged_cache_specs(cache, axis_sizes=None):
 
     Unlike the dense decode cache (``cache_specs``), the paged layout
     has no batch axis to data-shard: attention leaves are a single
-    shared pool ``(num_blocks, block_size, KV, hd)`` addressed by
+    shared pool ``(num_blocks, KV, rows, lanes)`` addressed by
     host-side page tables, and recurrent slabs are ``(num_slots, ...)``
     addressed by host-side slot ids.  The block/slot axis must stay
     **replicated** — every device needs every page resident so a slot's
@@ -224,10 +224,12 @@ def paged_cache_specs(cache, axis_sizes=None):
         rank = leaf.ndim - len(lead)
         if leaf.ndim == 0 or rank <= 0:
             return P(*((None,) * leaf.ndim))
-        if name in ("k", "v"):           # (nb, bs, KV, hd): shard head_dim
+        # (nb, KV, rows, lanes): shard the lanes, which hold head_dim
+        # (several positions' head_dims where the page is folded)
+        if name in ("k", "v"):
             spec = (None, None, None, "model")
         elif name in ("k_scale", "v_scale"):
-            # int8 pools' per-row scales (nb, bs, KV): head_dim is
+            # int8 pools' per-row scales (nb, KV, bs): head_dim is
             # already reduced away, and KV head counts are too small to
             # shard — replicate (a few bytes per block)
             spec = (None, None, None)

@@ -261,8 +261,12 @@ RECURRENT_BLOCKS = ("mamba", "mlstm", "slstm")
 
 
 def _paged_sublayer(p, cfg: ModelConfig, desc: Desc, x, state, page_table,
-                    lengths, t_valid, state_slots):
+                    lengths, t_valid, state_slots, layer=None):
     """Multi-token step through the paged serving cache.
+
+    ``state`` is this sublayer's pool or slabs, or with ``layer`` the
+    stack of them over the periodic layers, read and written at row
+    ``layer`` in place.
 
     Attention blocks read/write the shared block pool through the page
     table; recurrent blocks (mamba/mlstm/slstm) read/write their rows of
@@ -283,17 +287,18 @@ def _paged_sublayer(p, cfg: ModelConfig, desc: Desc, x, state, page_table,
             y, k, v, ks, vs = A.gqa_paged_step_quant(
                 p["attn"], cfg, h, state["k"], state["v"],
                 state["k_scale"], state["v_scale"],
-                page_table, lengths, t_valid)
+                page_table, lengths, t_valid, layer)
             state = {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
         else:
             y, k, v = A.gqa_paged_step(p["attn"], cfg, h,
                                        state["k"], state["v"],
-                                       page_table, lengths, t_valid)
+                                       page_table, lengths, t_valid, layer)
             state = {"k": k, "v": v}
     else:
-        ns = jax.tree.leaves(state)[0].shape[0]
+        lead = () if layer is None else (layer,)
+        ns = jax.tree.leaves(state)[0].shape[len(lead)]
         gathered = jax.tree.map(
-            lambda a: a[jnp.clip(state_slots, 0, ns - 1)], state)
+            lambda a: a[lead + (jnp.clip(state_slots, 0, ns - 1),)], state)
         fresh = lengths == 0
 
         def blank(a):
@@ -318,7 +323,8 @@ def _paged_sublayer(p, cfg: ModelConfig, desc: Desc, x, state, page_table,
             raise ValueError(block)
         idx = jnp.where(t_valid > 0, state_slots, ns)   # idle rows: OOB, drop
         state = jax.tree.map(
-            lambda a, b: a.at[idx].set(b.astype(a.dtype), mode="drop"),
+            lambda a, b: a.at[lead + (idx,)].set(b.astype(a.dtype),
+                                                 mode="drop"),
             state, new)
     x = x + y
     x = constrain(x, ("pod", "data"), None, None)
@@ -645,8 +651,13 @@ class TransformerLM:
                          shardings=None, kv_dtype: Optional[str] = None):
         """Shared block pool + recurrent state slabs.
 
-        Every attn layer gets (nb, bs, KV, hd) K/V stores with no batch
-        axis — slots share the pool through page tables.  Every
+        Every attn layer gets (nb, KV, rows, lanes) K/V stores with no
+        batch axis — slots share the pool through page tables.  A block
+        keeps each KV head's (bs, hd) rows together in row-major order,
+        folded onto 128 lanes where the head is narrower
+        (:func:`repro.models.attention.paged_page_shape`): the layout the
+        paged attention kernel copies a page in as, so no step relays
+        the pool.  Every
         recurrent layer gets fixed-size state slabs with a leading
         ``num_state_slots`` axis — slots own exactly one slab each (the
         engine's ``StateStore`` hands them out).  Periodic layers stack
@@ -654,7 +665,7 @@ class TransformerLM:
 
         ``kv_dtype="int8"`` switches the attn K/V stores to int8 with
         per-(block, row, head) float32 scale pools ``k_scale``/
-        ``v_scale`` of shape (nb, bs, KV) living in the same state dict
+        ``v_scale`` of shape (nb, KV, bs) living in the same state dict
         — they share the leading block axis, so COW forks, spill/restore
         gathers/scatters, and mesh placement all ride the existing
         pytree traversals untouched.  Recurrent slabs are never
@@ -678,23 +689,22 @@ class TransformerLM:
             raise ValueError(f"kv_dtype must be None or 'int8', "
                              f"got {kv_dtype!r}")
         kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+        page = (kv,) + A.paged_page_shape(block_size, hd)
 
         def store(desc):
             if desc[0] in RECURRENT_BLOCKS:
                 return _sublayer_state(cfg, desc, num_state_slots, 0, dtype)
             if kv_dtype == "int8":
                 return {
-                    "k": jnp.zeros((num_blocks, block_size, kv, hd),
-                                   jnp.int8),
-                    "v": jnp.zeros((num_blocks, block_size, kv, hd),
-                                   jnp.int8),
-                    "k_scale": jnp.zeros((num_blocks, block_size, kv),
+                    "k": jnp.zeros((num_blocks,) + page, jnp.int8),
+                    "v": jnp.zeros((num_blocks,) + page, jnp.int8),
+                    "k_scale": jnp.zeros((num_blocks, kv, block_size),
                                          jnp.float32),
-                    "v_scale": jnp.zeros((num_blocks, block_size, kv),
+                    "v_scale": jnp.zeros((num_blocks, kv, block_size),
                                          jnp.float32),
                 }
-            return {"k": jnp.zeros((num_blocks, block_size, kv, hd), dtype),
-                    "v": jnp.zeros((num_blocks, block_size, kv, hd), dtype)}
+            return {"k": jnp.zeros((num_blocks,) + page, dtype),
+                    "v": jnp.zeros((num_blocks,) + page, dtype)}
 
         cache: Dict[str, Any] = {}
         if self.prefix_descs:
@@ -811,27 +821,29 @@ class TransformerLM:
                 pc.append(st)
             new_cache["prefix"] = pc
 
-        def body(x, xs):
-            pp, cc = xs
-            states = {}
+        # the periodic pools ride the scan's carry whole and each layer
+        # updates its row in place: sliced out as scan inputs and
+        # stacked back as outputs, every step would copy them
+        def body(carry, xs):
+            x, cc = carry
+            pp, li = xs
+            cc = dict(cc)
             for j, desc in enumerate(self.period_descs):
-                x, st = _paged_sublayer(pp[f"s{j}"], self.cfg, desc, x,
-                                        cc[f"s{j}"], page_table, lengths,
-                                        t_valid, state_slots)
-                states[f"s{j}"] = st
-            return x, states
+                x, cc[f"s{j}"] = _paged_sublayer(
+                    pp[f"s{j}"], self.cfg, desc, x, cc[f"s{j}"], page_table,
+                    lengths, t_valid, state_slots, layer=li)
+            return (x, cc), None
 
+        carry = (x, cache["blocks"])
         if self.unroll:
-            per = []
             for i in range(self.n_periods):
-                x, st = body(x, jax.tree.map(
-                    lambda a: a[i], (params["blocks"], cache["blocks"])))
-                per.append(st)
-            blocks = jax.tree.map(lambda *xs: jnp.stack(xs, 0), *per)
+                carry, _ = body(carry, (jax.tree.map(
+                    lambda a: a[i], params["blocks"]), i))
         else:
-            x, blocks = jax.lax.scan(body, x, (params["blocks"],
-                                               cache["blocks"]))
-        new_cache["blocks"] = blocks
+            carry, _ = jax.lax.scan(
+                body, carry, (params["blocks"],
+                              jnp.arange(self.n_periods, dtype=jnp.int32)))
+        x, new_cache["blocks"] = carry
         if all_logits:
             return self._head(params, x), new_cache
         if tokens.shape[1] == 1:

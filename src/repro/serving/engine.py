@@ -546,6 +546,10 @@ class ServeEngine:
         self.n_device_steps = 0       # fused megasteps executed on device
         self.n_host_syncs = 0         # decode-loop device->host drains
         self.n_burst_early_exits = 0  # bursts cut short by all-done
+        # paged attention's reach (see loop_stats()): pages it read, and
+        # pages the page tables could address, summed over device steps
+        self.n_attn_pages_live = 0
+        self.n_attn_pages_capacity = 0
         # speculative-decode counters (see loop_stats())
         self.n_spec_rounds = 0        # draft+verify rounds executed
         self.n_spec_tokens = 0        # tokens emitted by those rounds
@@ -720,13 +724,25 @@ class ServeEngine:
         vs state uploads.  ``n_host_syncs / n_device_steps`` is the
         host-syncs-per-token figure the burst mode drives toward 1/K;
         ``n_state_uploads`` counts host->device slot-state rebuilds
-        (structural events only — steady decode adds none)."""
+        (structural events only — steady decode adds none).  Paged
+        engines add ``attn_kernel``, the path their attention takes
+        (``models.attention.paged_attention_path``: ``"pallas"`` or
+        ``"jnp"``), and over the mixed steps and plain bursts run,
+        ``n_attn_pages_live`` — the pages each slot with work holds
+        after the step, ``ceil((lengths + t_valid) / block_size)``,
+        which is what the kernel reads per layer — against
+        ``n_attn_pages_capacity``, batch x pages per slot a step, which
+        is what the jnp path gathers."""
         out = {"burst": self.burst, "max_burst": self.max_burst,
                "n_bursts": self.n_bursts,
                "n_device_steps": self.n_device_steps,
                "n_host_syncs": self.n_host_syncs,
                "n_burst_early_exits": self.n_burst_early_exits,
                "n_state_uploads": self._dev.n_uploads}
+        if self.paged:
+            out.update(attn_kernel=self._attn_kernel(),
+                       n_attn_pages_live=self.n_attn_pages_live,
+                       n_attn_pages_capacity=self.n_attn_pages_capacity)
         if self._spec:
             out.update(
                 spec_k=self.spec_k,
@@ -738,6 +754,19 @@ class ServeEngine:
                 spec_accept_rate=self.n_draft_accepted
                 / max(1, self.n_draft_proposed))
         return out
+
+    def _attn_kernel(self) -> str:
+        from ..models.attention import paged_attention_path
+        dtype = jnp.int8 if self._quant else self.cache_dtype
+        with self._sharding_ctx():
+            return paged_attention_path(dtype)
+
+    def _count_attn_pages(self, extents: np.ndarray) -> None:
+        """Add one device step's pages: ``extents`` holds each working
+        slot's cached length after the step."""
+        self.n_attn_pages_live += int(
+            (-(-extents // self.block_size)).sum())
+        self.n_attn_pages_capacity += self._page_table.size
 
     def compile_stats(self) -> Dict[str, int]:
         """Compilation counts of the jitted hot-path functions.  The
@@ -1216,6 +1245,9 @@ class ServeEngine:
             self.n_burst_early_exits += 1
         fresh: Dict[int, List[int]] = {}
         for kstep in range(n_steps):
+            if paged:
+                self._count_attn_pages(
+                    self._lengths[valid[kstep]] + 1)
             for i, slot in enumerate(self._slots):
                 if slot is None or not valid[kstep, i]:
                     continue
@@ -1480,6 +1512,8 @@ class ServeEngine:
         self._dev.adopt(st)
         self.n_prefill_chunks += 1
         self.n_device_steps += 1
+        self._count_attn_pages(self._lengths[t_valid > 0]
+                               + t_valid[t_valid > 0])
         with TraceAnnotation("engine.drain", t=T):
             if self.trace_logits:
                 sampled_np, logits_np = jax.device_get((sampled, logits))

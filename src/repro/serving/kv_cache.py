@@ -30,13 +30,15 @@ instead carves one shared pool of ``num_blocks`` fixed-size blocks:
     ``models/attention.py`` (the models layer must not depend on
     serving) and are re-exported here as the cache-layout API.
 
-Layout convention: storage is ``(num_blocks, block_size, ...)``; a page
-table row ``page_table[b]`` lists the physical block of each logical
-page of slot ``b`` (unused entries may hold any valid block id — reads
-beyond a slot's true length are masked by the attention kernel, so
-stale pointers are harmless).  Logical position ``l`` of slot ``b``
-lives at flat row ``page_table[b, l // block_size] * block_size +
-l % block_size``.
+Layout convention: storage is ``(num_blocks, KV, block_size, ...)``
+(int8 scale pools ``(num_blocks, KV, block_size)``), so a block holds
+each KV head's rows together; a page table row ``page_table[b]`` lists
+the physical block of each logical page of slot ``b`` (unused entries
+may hold any valid block id — the paged attention kernel reads no page
+past a slot's true length and the jnp path masks them, so stale
+pointers are harmless).  Logical position ``l`` of slot ``b``, KV head
+``h``, lives at ``storage[page_table[b, l // block_size], h,
+l % block_size]``.
 
 Content addressing uses *chain digests*: the key of block ``p`` in a
 sequence is ``sha256(digest(p-1) || tokens of page p)`` with a fixed

@@ -100,6 +100,81 @@ def test_decode_attention_kernel(B, H, KV, C, hd, length):
                                atol=1e-5, rtol=1e-5)
 
 
+# -- paged attention over live pages ---------------------------------------------
+
+def _paged_case(T, G, hd, bs, lengths, t_valid, *, KV=2, P=4, seed=0):
+    """Random q and pools in the engine's folded layout, each slot on
+    its own blocks, with the jnp path's answer for the real rows."""
+    from repro.models.attention import (paged_attention, paged_gather,
+                                        paged_page_shape)
+    r = np.random.default_rng(seed)
+    B, H = len(lengths), KV * G
+    nb = B * P + 3
+    rows, lanes = paged_page_shape(bs, hd)
+    q = jnp.asarray(r.standard_normal((B, T, H, hd)), jnp.float32)
+    k = jnp.asarray(r.standard_normal((nb, KV, rows, lanes)), jnp.float32)
+    v = jnp.asarray(r.standard_normal((nb, KV, rows, lanes)), jnp.float32)
+    pt = jnp.asarray(r.permutation(nb)[:B * P].reshape(B, P), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    t_valid = jnp.asarray(t_valid, jnp.int32)
+    unfold = lambda a: a.reshape(nb, KV, bs, hd)
+    pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    ref = paged_attention(q, paged_gather(unfold(k), pt),
+                          paged_gather(unfold(v), pt), pos)
+    return q, k, v, pt, lengths, t_valid, ref
+
+
+@pytest.mark.parametrize("T,bs,lengths,t_valid,poison", [
+    (1, 16, [0, 15, 16, 33], [1, 1, 1, 1], False),   # burst, on/off pages
+    (1, 16, [5, 47, 32, 0], [1, 0, 1, 1], False),    # an idle slot
+    (4, 16, [0, 13, 29, 60], [4, 3, 0, 4], False),   # ragged chunks
+    (4, 8, [16, 6, 0, 23], [2, 4, 1, 0], False),     # two pages per row
+    (4, 16, [12, 28, 0, 44], [4, 4, 2, 1], True),    # dead pages poisoned
+    (1, 16, [3, 16, 31, 0], [1, 1, 1, 0], True),
+])
+def test_paged_attention_kernel(T, bs, lengths, t_valid, poison):
+    """The Pallas kernel (interpret mode) equals the jnp path on every
+    real query row, writes zeros for idle slots, and reads no page
+    past a slot's live extent: with every block that no slot's extent
+    reaches filled with NaN, the output stays finite and unchanged."""
+    from repro.kernels.decode_attention.ops import paged_attention_bthd
+    G, hd = 3, 64
+    q, k, v, pt, lengths, t_valid, ref = _paged_case(T, G, hd, bs, lengths,
+                                                     t_valid)
+    if poison:
+        live = np.zeros(k.shape[0], bool)
+        for b in range(len(lengths)):
+            n = -(-int(lengths[b] + t_valid[b]) // bs) if t_valid[b] else 0
+            live[np.asarray(pt[b, :n])] = True
+        dead = jnp.asarray(~live)[:, None, None, None]
+        k = jnp.where(dead, jnp.nan, k)
+        v = jnp.where(dead, jnp.nan, v)
+    out = np.asarray(paged_attention_bthd(q, k, v, pt, lengths, t_valid))
+    assert np.isfinite(out).all()
+    for b in range(len(lengths)):
+        n = int(t_valid[b])
+        np.testing.assert_allclose(out[b, :n], np.asarray(ref[b, :n]),
+                                   atol=1e-5, rtol=1e-5)
+        if n == 0:
+            assert not out[b].any()
+
+
+def test_paged_attention_path(monkeypatch):
+    """The path is chosen from what the trace can observe: the jnp path
+    on CPU; the kernel on a TPU backend with no mesh and a float pool;
+    the jnp path again for an int8 pool or under an active mesh."""
+    from jax.sharding import Mesh
+
+    from repro.models import attention as A
+    assert A.paged_attention_path(jnp.bfloat16) == "jnp"
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    assert A.paged_attention_path(jnp.bfloat16) == "pallas"
+    assert A.paged_attention_path(jnp.float32) == "pallas"
+    assert A.paged_attention_path(jnp.int8) == "jnp"
+    with Mesh(np.array(jax.devices()[:1]), ("model",)):
+        assert A.paged_attention_path(jnp.bfloat16) == "jnp"
+
+
 # -- int8 paged decode attention ----------------------------------------------
 
 @pytest.mark.parametrize("B,H,KV,hd,nb,bs,P", [
@@ -107,27 +182,28 @@ def test_decode_attention_kernel(B, H, KV, C, hd, length):
 ])
 def test_paged_decode_attention_quant_kernel(B, H, KV, hd, nb, bs, P):
     """Int8 kernel == dequantize-then-attend oracle (exact), and the
-    int8 round-trip vs the f32 kernel stays within drift tolerance."""
+    int8 round-trip vs the float kernel stays within drift tolerance."""
     from repro.kernels.decode_attention import ops
     from repro.kernels.decode_attention.ref import (
         paged_decode_attention_quant_ref)
-    from repro.models.attention import quantize_kv
-    kf = jnp.asarray(rng.standard_normal((nb, bs, KV, hd)), jnp.float32)
-    vf = jnp.asarray(rng.standard_normal((nb, bs, KV, hd)), jnp.float32)
+    from repro.models.attention import paged_page_shape, quantize_kv
+    kf = jnp.asarray(rng.standard_normal((nb, KV, bs, hd)), jnp.float32)
+    vf = jnp.asarray(rng.standard_normal((nb, KV, bs, hd)), jnp.float32)
     kq, ks = quantize_kv(kf)
     vq, vs = quantize_kv(vf)
-    assert kq.dtype == jnp.int8 and ks.shape == (nb, bs, KV)
+    assert kq.dtype == jnp.int8 and ks.shape == (nb, KV, bs)
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
     pt = jnp.asarray(np.stack([rng.permutation(nb)[:P] for _ in range(B)]),
                      jnp.int32)
     lengths = jnp.asarray(rng.integers(1, P * bs + 1, B), jnp.int32)
     o = ops.paged_decode_attention_quant_bhd(q, kq, vq, ks, vs, pt, lengths)
-    orf = paged_decode_attention_quant_ref(
-        q[:, 0], jnp.moveaxis(kq, 2, 1), jnp.moveaxis(vq, 2, 1),
-        jnp.moveaxis(ks, 2, 1), jnp.moveaxis(vs, 2, 1), pt, lengths)
+    orf = paged_decode_attention_quant_ref(q[:, 0], kq, vq, ks, vs, pt,
+                                           lengths)
     np.testing.assert_allclose(np.asarray(o[:, 0]), np.asarray(orf),
                                atol=1e-5, rtol=1e-5)
-    of = ops.paged_decode_attention_bhd(q, kf, vf, pt, lengths)
+    page = (nb, KV) + paged_page_shape(bs, hd)
+    of = ops.paged_attention_bthd(q, kf.reshape(page), vf.reshape(page), pt,
+                                  lengths - 1, jnp.ones_like(lengths))
     assert float(jnp.max(jnp.abs(o - of))) < 5e-2   # int8 drift, not exact
 
 
@@ -145,7 +221,7 @@ def test_interpret_defaults_to_backend_autodetect():
     from repro.kernels import default_interpret
     from repro.kernels.decode_attention import kernel as dk
     from repro.kernels.decode_attention.ops import (
-        decode_attention_bhd, paged_decode_attention_bhd,
+        decode_attention_bhd, paged_attention_bthd,
         paged_decode_attention_quant_bhd)
     from repro.kernels.flash_attention.kernel import flash_attention
     from repro.kernels.flash_attention.ops import flash_attention_bshd
@@ -155,12 +231,12 @@ def test_interpret_defaults_to_backend_autodetect():
     from repro.kernels.ssm_scan.ops import selective_scan
     from repro.kernels.transform.kernel import fused_transform_2d
     from repro.kernels.transform.ops import fused_transform
-    for fn in (decode_attention_bhd, paged_decode_attention_bhd,
+    for fn in (decode_attention_bhd, paged_attention_bthd,
                paged_decode_attention_quant_bhd, flash_attention_bshd,
                topk, selective_scan, fused_transform):
         sig = inspect.signature(fn)
         assert sig.parameters["interpret"].default is None, fn.__name__
-    for fn in (dk.decode_attention, dk.paged_decode_attention,
+    for fn in (dk.decode_attention, dk.paged_attention,
                dk.paged_decode_attention_quant, flash_attention,
                gating_topk, selective_scan_kernel, fused_transform_2d):
         param = inspect.signature(fn).parameters["interpret"]

@@ -498,6 +498,52 @@ if HAVE_HYPOTHESIS:
         _run_state_sequence(ops)
 
 
+# -- paged_scatter: the pool layout, written position by position ------------
+
+@pytest.mark.parametrize("bs,hd,T,layers", [
+    (16, 64, 5, 2),    # folded: two positions a 128-lane row
+    (8, 32, 7, 0),     # folded four to a row, one unstacked pool
+    (4, 16, 3, 1),     # a page too small to fold: (bs, hd) as it is
+])
+def test_paged_scatter_writes_each_position(bs, hd, T, layers):
+    """``paged_scatter`` puts token t of slot b at position lengths + t
+    of head h's rows in block ``page_table[b, pos // bs]`` -- in the
+    unfolded (bs, hd) view of the pool -- for real tokens only, and
+    leaves every other element, other layers' included, as it was; a
+    scale pool's rows likewise."""
+    from repro.models.attention import paged_page_shape, paged_scatter
+    rng = np.random.default_rng(bs + hd)
+    KV, nb, P, B = 3, 12, 3, 4
+    rows, lanes = paged_page_shape(bs, hd)
+    lead = (layers,) if layers else ()
+    layer = layers - 1 if layers else None
+    pool = rng.standard_normal(lead + (nb, KV, rows, lanes)).astype(np.float32)
+    scale = rng.standard_normal(lead + (nb, KV, bs)).astype(np.float32)
+    vals = rng.standard_normal((B, T, KV, hd)).astype(np.float32)
+    svals = rng.standard_normal((B, T, KV)).astype(np.float32)
+    pt = rng.permutation(nb)[:B * P].reshape(B, P).astype(np.int32)
+    lengths = np.array([0, bs - 1, 2 * bs - 2, P * bs - 2], np.int32)
+    t_valid = np.array([T, T, 0, T], np.int32)
+    args = [jnp.asarray(a) for a in (pt, lengths, t_valid)] + [layer]
+    got = np.asarray(paged_scatter(jnp.asarray(pool), jnp.asarray(vals),
+                                   *args))
+    got_s = np.asarray(paged_scatter(jnp.asarray(scale), jnp.asarray(svals),
+                                     *args))
+    want = pool.reshape(lead + (nb, KV, bs, hd)).copy()
+    want_s = scale.copy()
+    at = (layer,) if layers else ()
+    for b in range(B):
+        for t in range(int(t_valid[b])):
+            pos = int(lengths[b]) + t
+            if pos < P * bs:         # past the page table: dropped
+                want[at + (pt[b, pos // bs], slice(None), pos % bs)] = \
+                    vals[b, t]
+                want_s[at + (pt[b, pos // bs], slice(None), pos % bs)] = \
+                    svals[b, t]
+    np.testing.assert_array_equal(got, want.reshape(pool.shape))
+    np.testing.assert_array_equal(got_s, want_s)
+
+
 # -- paged decode vs dense decode: bit-for-bit --------------------------------
 
 def _copy_dense_cache_to_pages(dense_cache, paged_cache, page_table,
@@ -516,9 +562,12 @@ def _copy_dense_cache_to_pages(dense_cache, paged_cache, page_table,
         src = np.asarray(dense_leaf)[:, 0]         # strip batch: (L, ...)
         out = np.asarray(paged_leaf).copy()
         if key in ("k", "v"):                      # (L, C, kv, hd) -> blocks
+            # the pool's pages (L, nb, kv, rows, lanes), unfolded to
+            # (L, nb, kv, bs, hd) -- a view, so writes land in ``out``
+            pages = out.reshape(out.shape[:3] + (block_size, -1))
             for logical in range(min(cap, src.shape[1])):
                 blk, off = pt[logical // block_size], logical % block_size
-                out[:, blk, off] = src[:, logical]
+                pages[:, blk, :, off] = src[:, logical]
         else:                                      # state -> its slab row
             out[:, slab] = src
         return jnp.asarray(out)
@@ -655,6 +704,24 @@ def test_eviction_frees_all_blocks(family_model):
         assert eng.state_store.n_free == eng.num_state_slots
     else:
         assert eng.state_store is None
+
+
+def test_loop_stats_count_attention_pages(tiny_model):
+    """``loop_stats()`` names the attention path (the jnp one on CPU)
+    and counts, per device step, the pages each working slot holds
+    after it against the page table's capacity.  A 9-token prompt in
+    8-token chunks, then 3 decode steps, 4-token pages: extents 8, 9,
+    10, 11, 12 are 2, 3, 3, 3, 3 pages over 5 steps of 8 pages."""
+    model, params = tiny_model
+    eng = ServeEngine(model, params, batch_size=1, capacity=32,
+                      max_new_tokens=4, block_size=4, prefill_chunk=8,
+                      burst=8)
+    eng.serve([np.arange(1, 10, dtype=np.int32)])
+    stats = eng.loop_stats()
+    assert stats["attn_kernel"] == "jnp"
+    assert stats["n_device_steps"] == 5
+    assert stats["n_attn_pages_live"] == 2 + 3 + 3 + 3 + 3
+    assert stats["n_attn_pages_capacity"] == 5 * 8
 
 
 def test_blocks_freed_as_each_request_finishes(tiny_model):
@@ -1015,7 +1082,9 @@ def test_no_sharing_between_disjoint_prompts(tiny_model):
 # -- paged decode-attention kernel vs oracle ----------------------------------
 
 def test_paged_kernel_matches_paged_ref():
-    from repro.kernels.decode_attention.kernel import paged_decode_attention
+    """One decode token per slot (T=1): the paged attention kernel
+    equals the paged decode oracle over the same cache content."""
+    from repro.kernels.decode_attention.kernel import paged_attention
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref
     rng = np.random.default_rng(0)
     B, H, KV, hd = 3, 4, 2, 16
@@ -1025,29 +1094,34 @@ def test_paged_kernel_matches_paged_ref():
     vp = jnp.asarray(rng.standard_normal((nb, KV, bs, hd)), jnp.float32)
     pt = jnp.asarray(rng.choice(nb, size=(B, P), replace=False).astype(np.int32))
     lengths = jnp.asarray([5, P * bs, 1], jnp.int32)
-    o = paged_decode_attention(q, kp, vp, pt, lengths, interpret=True)
+    # the token at position lengths - 1 is this step's: lengths - 1 cached
+    o = paged_attention(q[:, None], kp[None], vp[None], pt, lengths - 1,
+                        jnp.ones_like(lengths), 0, interpret=True)
     r = paged_decode_attention_ref(q, kp, vp, pt, lengths)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+    np.testing.assert_allclose(np.asarray(o[:, 0]), np.asarray(r),
                                atol=1e-5, rtol=1e-5)
 
 
 def test_paged_ops_wrapper_matches_ref_in_engine_layout():
-    """ops.paged_decode_attention_bhd takes the ServeEngine leaf layout
-    (num_blocks, block_size, KV, hd); its transposition into the kernel
-    layout must preserve the oracle's result."""
+    """ops.paged_attention_bthd takes the ServeEngine leaf layout — each
+    KV head's page folded onto 128 lanes, ``paged_page_shape`` — and
+    reads it as is: the result equals the oracle on the unfolded pool."""
     from repro.kernels.decode_attention import ops
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+    from repro.models.attention import paged_page_shape
     rng = np.random.default_rng(4)
     B, H, KV, hd = 2, 4, 2, 16
     nb, bs, P = 10, 8, 3
+    assert paged_page_shape(bs, hd) == (1, 128)
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
-    k_eng = jnp.asarray(rng.standard_normal((nb, bs, KV, hd)), jnp.float32)
-    v_eng = jnp.asarray(rng.standard_normal((nb, bs, KV, hd)), jnp.float32)
+    k_eng = jnp.asarray(rng.standard_normal((nb, KV, 1, 128)), jnp.float32)
+    v_eng = jnp.asarray(rng.standard_normal((nb, KV, 1, 128)), jnp.float32)
     pt = jnp.asarray(rng.choice(nb, size=(B, P), replace=False).astype(np.int32))
     lengths = jnp.asarray([6, 20], jnp.int32)
-    o = ops.paged_decode_attention_bhd(q, k_eng, v_eng, pt, lengths)
-    r = paged_decode_attention_ref(q[:, 0], jnp.moveaxis(k_eng, 2, 1),
-                                   jnp.moveaxis(v_eng, 2, 1), pt, lengths)
+    o = ops.paged_attention_bthd(q, k_eng, v_eng, pt, lengths - 1,
+                                 jnp.ones_like(lengths))
+    r = paged_decode_attention_ref(q[:, 0], k_eng.reshape(nb, KV, bs, hd),
+                                   v_eng.reshape(nb, KV, bs, hd), pt, lengths)
     assert o.shape == (B, 1, H, hd)
     np.testing.assert_allclose(np.asarray(o[:, 0]), np.asarray(r),
                                atol=1e-5, rtol=1e-5)

@@ -116,7 +116,7 @@ def test_paged_cache_specs_kv_sharded_on_head_dim():
     cache = _paged_struct("transformer")
     leaves = _leaves_with_paths(paged_cache_specs(cache))
     k = next(v for p, v in leaves.items() if p.endswith("/k"))
-    assert k[-1] == "model"  # (nb, bs, KV, hd): head_dim on TP axis
+    assert k[-1] == "model"  # (nb, KV, rows, lanes): head_dim on TP axis
 
 
 def test_paged_cache_specs_divisibility_filter():

@@ -51,14 +51,20 @@ def _compile(fn, *args):
     return compiled
 
 
-def test_paged_decode_attention_compiles(one_chip):
-    from repro.kernels.decode_attention.kernel import paged_decode_attention
+@pytest.mark.parametrize("T", [1, 32])
+def test_paged_decode_attention_compiles(one_chip, T):
+    """The served paged attention at smollm-360m's cell: 64 slots of 128
+    pages over a 1,792-block pool stacked for 32 layers, one token a
+    slot (decode bursts) and a 32-token chunk (mixed steps)."""
+    from repro.kernels.decode_attention.kernel import paged_attention
+    from repro.models.attention import paged_page_shape
     s = functools.partial(_spec, one_chip)
-    _compile(functools.partial(paged_decode_attention, interpret=False),
-             s((B, H, HD), jnp.bfloat16),
-             s((NUM_BLOCKS, KV, BS, HD), jnp.bfloat16),
-             s((NUM_BLOCKS, KV, BS, HD), jnp.bfloat16),
-             s((B, PAGES), jnp.int32), s((B,), jnp.int32))
+    slots, pages, blocks, layers = 64, 128, 1792, 32
+    pool = s((layers, blocks, KV) + paged_page_shape(BS, HD), jnp.bfloat16)
+    _compile(functools.partial(paged_attention, interpret=False),
+             s((slots, T, H, HD), jnp.bfloat16), pool, pool,
+             s((slots, pages), jnp.int32), s((slots,), jnp.int32),
+             s((slots,), jnp.int32), s((), jnp.int32))
 
 
 def test_paged_decode_attention_quant_compiles(one_chip):
